@@ -110,7 +110,7 @@ def run_restore_invocations(orch, name, image, touch_set, n_invocations=3):
         n_flt = ri.instance.stats["fault_rdma"] - flt0
         inv_lat.append(ri.ledger.total() - led0 + n_flt * FAULT_TRAP_S)
     ri.engine.install_all_sync()
-    bit_identical = bool(np.array_equal(ri.instance.image.buf, image.buf))
+    bit_identical = bool(np.array_equal(ri.instance.image_bytes(), image.buf))
     version = ri.borrow.version
     stats = dict(ri.instance.stats)
     ri.shutdown()
@@ -268,7 +268,7 @@ def paced_drain_restore(orch, name, image, visit, policy,
                     policy.order_extents(eng, faulting_page=p))}
                 q = deque(sorted(q, key=lambda e: rank.get(e[0], len(rank))))
     eng.install_all_sync()
-    bit_identical = bool(np.array_equal(ri.instance.image.buf, image.buf))
+    bit_identical = bool(np.array_equal(ri.instance.image_bytes(), image.buf))
     ri.shutdown()
     return {
         "demand_faults": n_demand,
@@ -299,7 +299,7 @@ def run_prefetch_ab_point(seed: int, quick: bool,
         for j in range(0, len(visit), 16):
             ri.engine.touch_pages(visit[j:j + 16])
         ri.engine.install_all_sync()
-        assert np.array_equal(ri.instance.image.buf, img.buf)
+        assert np.array_equal(ri.instance.image_bytes(), img.buf)
         ri.shutdown()
 
     hm = heat.find("shift", regions.version)
@@ -375,7 +375,7 @@ def run_capacity(quick: bool = False) -> dict:
     for i in range(4):
         ri = orch.restore(f"cap{i}")
         ri.engine.install_all_sync()
-        bit[f"cap{i}"] = bool(np.array_equal(ri.instance.image.buf,
+        bit[f"cap{i}"] = bool(np.array_equal(ri.instance.image_bytes(),
                                              images[f"cap{i}"].buf))
         hot_pages[f"cap{i}"] = ri.borrow.regions.n_hot
         ri.shutdown()

@@ -53,7 +53,7 @@ def run(workload: str = "chameleon", concurrency: int = 32) -> dict:
     for page in range(ri.instance.image.total_pages):
         if not ri.instance.present[page]:
             ri.engine.access(page)
-    bit_identical = bool(np.array_equal(ri.instance.image.buf, bw.image.buf))
+    bit_identical = bool(np.array_equal(ri.instance.image_bytes(), bw.image.buf))
     inst_stats = dict(ri.instance.stats)
     prefetch_stats = dict(ri.engine.prefetch_stats)
     ledger = {k: v for k, v in ri.ledger.seconds.items()}

@@ -36,8 +36,10 @@ def run(verify_kernel: bool = True) -> dict:
         }
         if verify_kernel:
             from repro.kernels import zero_detect
+            from repro.kernels.backend import on_tpu
             mat = bw.image.pages_matrix()[: 4096].view(np.float32)
-            kb = np.asarray(zero_detect(mat, use_pallas=True, interpret=True)).astype(bool)
+            kb = np.asarray(zero_detect(mat, use_pallas=True,
+                                        interpret=not on_tpu())).astype(bool)
             nb = ~bw.image.pages_matrix()[: 4096].any(axis=1)
             row["kernel_bitmap_match"] = bool(np.array_equal(kb, nb))
         # beyond-paper: zstd cold-tier ratio (even sample of 2k cold pages)

@@ -118,7 +118,7 @@ def run_point(conc: int, shared: bool, same_snapshot: bool, images,
     for k, ri in enumerate(ris):
         src = images[0 if same_snapshot else k][0]
         ok = bool(ri.instance.present.all()) and \
-            bool(np.array_equal(ri.instance.image.buf, src.buf))
+            bool(np.array_equal(ri.instance.image_bytes(), src.buf))
         identical = identical and ok
         t_exec = ri.ledger.total()
         t_model = modeled_concurrent_restore_s(ri.engine.reader, groups,

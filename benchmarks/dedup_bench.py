@@ -97,7 +97,7 @@ def restore_and_verify(pool, master, name, img):
         eng = RestoreEngine(reader, inst, rdma_engine=None)
         eng.install_all_sync()
         ok = bool(inst.all_present()
-                  and np.array_equal(inst.image.buf, img.buf))
+                  and np.array_equal(inst.image_bytes(), img.buf))
         return ok, float(inst.ledger.total())
     finally:
         borrow.release()

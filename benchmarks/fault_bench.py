@@ -122,7 +122,7 @@ def run_sweep(sweep: str, n_restores: int, img, ws, armed: bool = True):
                             clock=clock)
         eng.install_all_sync(use_batch=True)
         ok = ok and bool(inst.all_present()
-                         and np.array_equal(inst.image.buf, img.buf))
+                         and np.array_equal(inst.image_bytes(), img.buf))
         restore_s.append(float(led.total()))
         ledgers.append(dict(led.seconds))
         totals["retries"] += len(eng.retry_trace)

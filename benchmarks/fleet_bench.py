@@ -148,7 +148,7 @@ def verify_restores(pool, master, images, fleet, n_sample):
             inst = Instance(StateImage.empty_like(images[f.fn_id].manifest))
             RestoreEngine(reader, inst, rdma_engine=None).install_all_sync()
             ok.append(bool(inst.all_present() and
-                           np.array_equal(inst.image.buf,
+                           np.array_equal(inst.image_bytes(),
                                           images[f.fn_id].buf)))
         finally:
             borrow.release()

@@ -9,7 +9,8 @@ Three layers, cleanly separated so CI can gate what is deterministic:
 * **measured** — wall-clock with the timing discipline the old bench lacked:
   first call (compile) timed separately, then warm steady-state reps with
   ``jax.block_until_ready``, GB/s reported.  ``--quick`` runs the Pallas
-  kernels in interpret mode at tiny shapes (fast CI tier, no TPU); the
+  kernels at tiny shapes (compiled on a TPU; in interpret mode elsewhere,
+  the fast CI tier); the
   default tier runs the dispatch path (compiled Pallas on TPU, jit'd oracle
   elsewhere) at large shapes (nightly).  Wall-clock is informational — this
   box is not the target — and is never gated.
@@ -35,6 +36,7 @@ import jax
 import numpy as np
 
 from repro.core.pagestore import PAGE_SIZE
+from repro.kernels.backend import on_tpu
 from repro.kernels import (
     fused_publish,
     fused_restore,
@@ -181,16 +183,16 @@ def _mk_workload(n: int, seed: int = 0):
 
 
 def measured_section(tier: str) -> dict:
-    """tier='interpret': real Pallas kernels in interpret mode, tiny shapes.
-    tier='dispatch': default dispatch (compiled Pallas on TPU, jit'd oracle
-    elsewhere), larger shapes."""
+    """tier='interpret': the Pallas kernels at tiny shapes — compiled on a
+    TPU, in the interpreter elsewhere.  tier='dispatch': the backend's data
+    plane (compiled Pallas on TPU, jit'd oracle elsewhere), larger shapes."""
     if tier == "interpret":
         n, m, reps = 64, 16, 2
-        disp = {"use_pallas": True, "interpret": True}
+        disp = {"use_pallas": True, "interpret": not on_tpu()}
         blk = {"block_pages": 8}
     else:
         n, m, reps = 8192, 2048, 5
-        disp = {}
+        disp = {"use_pallas": on_tpu()}
         blk = {}
     pages, u32, ws = _mk_workload(n)
     rows = []
@@ -264,7 +266,7 @@ def measured_section(tier: str) -> dict:
     zb_, csum, hot, cold, hhash, chash = piecemeal_publish()
     fp = do_fused_publish()
     f_out, f_csums = do_fused_restore()
-    f_out_u32 = np.asarray(f_out).reshape(n, PAGE_SIZE).view(np.uint32)
+    f_out_u32 = np.asarray(f_out).view(np.uint32).reshape(n, -1)
     p_csums, p_out = piecemeal_restore()
     identical = bool(
         np.array_equal(fp.zero_bitmap, zb_)

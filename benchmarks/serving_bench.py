@@ -54,7 +54,7 @@ def _one_restore(pool, regions, image, mode: str) -> dict:
         "total_modeled_s": ledger.total(),
         "ledger_s": dict(ledger.seconds),
         "wall_s": wall_s,
-        "bit_identical": bool(np.array_equal(inst.image.buf, image.buf)),
+        "bit_identical": bool(np.array_equal(inst.image_bytes(), image.buf)),
         "bytes_installed": inst.stats["bytes_installed"],
         "cxl_bytes_read": view.stats["bytes_read"],
         "uffd_batches": inst.stats["uffd_batches"],

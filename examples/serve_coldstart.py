@@ -62,7 +62,8 @@ def main(argv=None):
     print(f"\nwarm restore: time-to-hot={st['time_to_hot_s']*1e3:.1f}ms "
           f"time-to-full={st['time_to_full_s']*1e3:.1f}ms "
           f"(pre-installed {st['instance']['pre_installed']} hot pages, "
-          f"{st['instance']['fault_rdma']} async RDMA cold faults)")
+          f"{st['instance']['uffd_copies'] - st['instance']['pre_installed']} "
+          f"cold pages in RDMA batches)")
     toks = out["instance"].generate(jnp.asarray([[1, 2, 3]], jnp.int32),
                                     2 if args.quick else 8)
     print("served tokens:", toks[0].tolist())

@@ -70,12 +70,13 @@ def fnv1a_pages(pages_matrix: np.ndarray) -> np.ndarray:
 
 def pallas_hash_fn(pages_matrix: np.ndarray) -> np.ndarray:
     """The TPU-shaped alternative: the ``page_checksum`` polynomial rolling
-    hash (Pallas kernel on TPU, jnp oracle elsewhere), adapted to the
-    ``HashFn`` signature.  Weaker (32-bit) than FNV-1a-64, which is fine —
-    the store byte-verifies every hash match before sharing."""
+    hash (the compiled kernel on TPU, the jnp oracle elsewhere), adapted to
+    the ``HashFn`` signature.  Weaker (32-bit) than FNV-1a-64, which is fine
+    — the store byte-verifies every hash match before sharing."""
+    from ..kernels.backend import on_tpu
     from ..kernels.page_checksum.ops import page_checksum
 
-    return np.asarray(page_checksum(pages_matrix))
+    return np.asarray(page_checksum(pages_matrix, use_pallas=on_tpu()))
 
 
 # Marker consumed by the fused publish path (core/snapshot.py): the fused

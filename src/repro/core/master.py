@@ -232,7 +232,11 @@ class PoolMaster:
         self.pool = pool
         # default fused publish sweep (kernels/snapshot_fuse): used by every
         # publish this master drives — including re-curation rebuilds and
-        # capacity demotions — unless the call site overrides it
+        # capacity demotions — unless the call site overrides it.  Unset, it
+        # is the backend's data plane: the compiled kernel on a TPU
+        if publish_fn is None:
+            from ..kernels.snapshot_fuse.ops import default_publish_fn
+            publish_fn = default_publish_fn()
         self.publish_fn = publish_fn
         self.clock = clock or getattr(pool, "clock", None) or REAL_CLOCK
         self.catalog = catalog or Catalog(clock=self.clock)

@@ -14,6 +14,8 @@ host, with hot-chunk fan-out across same-snapshot restores (DESIGN.md §10).
 (``kernels/snapshot_fuse.FusedScatter``, DESIGN.md §13); the fused form is
 additionally bound per restore to the snapshot's publish-time checksum
 table, so pre-install and fan-out installs verify content as they land.
+Unset, it is the backend's data plane: on a TPU the compiled fused kernel,
+whose restoring instances live in HBM.
 ``use_node_server=False`` keeps the legacy per-instance engine path (one
 private engine + completion thread per restore) for A/B comparison; that
 path registers each restore as its own stream on the host's link arbiters
@@ -86,6 +88,11 @@ class Orchestrator:
             prefetch_policy = resolve_policy(
                 prefetch_policy, max_extent_pages, "Orchestrator")
         self.prefetch_policy = prefetch_policy
+        if scatter_fn is None:
+            # the backend's restore data plane: on a TPU the compiled fused
+            # kernel installs into HBM-resident instance memory
+            from ..kernels.snapshot_fuse.ops import default_scatter_fn
+            scatter_fn = default_scatter_fn()
         self.scatter_fn = scatter_fn
         self.node_server = node_server
         self.use_node_server = bool(use_node_server) and use_async_rdma

@@ -18,8 +18,8 @@ PAGE_SIZE = 4096  # bytes — matches the paper's 4 KiB guest pages
 
 # zero_scan(pages_matrix uint8[N, PAGE_SIZE]) -> bool[N] (True = all-zero).
 # Pluggable backend for the publish-path zero scan: the numpy oracle by
-# default; ``set_zero_scan_backend`` swaps in kernels/zero_detect (Pallas on
-# TPU, interpret elsewhere) — parity-asserted in tests/test_fused_kernels.py.
+# default; ``set_zero_scan_backend`` swaps in kernels/zero_detect (compiled
+# on TPU) — parity-asserted in tests/test_fused_kernels.py.
 ZeroScanFn = Callable[[np.ndarray], np.ndarray]
 
 _zero_scan_backend: Optional[ZeroScanFn] = None
@@ -39,13 +39,15 @@ def set_zero_scan_backend(fn: Optional[ZeroScanFn]) -> Optional[ZeroScanFn]:
     return prev
 
 
-def pallas_zero_scan(pages_matrix: np.ndarray) -> np.ndarray:
+def pallas_zero_scan(pages_matrix: np.ndarray, *,
+                     interpret: bool = False) -> np.ndarray:
     """kernels/zero_detect adapted to the ``ZeroScanFn`` signature (same
-    output as the oracle, asserted equal in tests)."""
+    output as the oracle, asserted equal in tests).  The kernel runs
+    compiled; ``interpret=True`` runs it in the interpreter (tests)."""
     from ..kernels.zero_detect.ops import zero_detect
 
-    u32 = pages_matrix.view(np.uint32).reshape(pages_matrix.shape[0], -1)
-    return np.asarray(zero_detect(u32, use_pallas=True, interpret=None)) != 0
+    return np.asarray(zero_detect(pages_matrix, use_pallas=True,
+                                  interpret=interpret)) != 0
 
 
 def num_pages(nbytes: int) -> int:

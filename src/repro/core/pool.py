@@ -298,7 +298,11 @@ class HostView:
         self.tier = tier
         self.ledger = ledger or TimeLedger()
         self.arbiter = tier.arbiter_for(host)
-        self._cache: Dict[int, np.ndarray] = {}  # line index -> 64B snapshot
+        # the private cache: a per-line "cached" bitmap over the tier plus a
+        # shadow of each cached line's bytes as they were when first read
+        # (both allocated on first read; untouched memory is never committed)
+        self._cached: Optional[np.ndarray] = None
+        self._shadow: Optional[np.ndarray] = None
         self.stats = {"cached_reads": 0, "pool_reads": 0, "flushed_lines": 0,
                       "bytes_read": 0}
 
@@ -311,22 +315,23 @@ class HostView:
             # the host CXL.mem link: brownout windows apply here (owner-side
             # pool-fabric reads via MemoryTier.read are NOT browned out)
             fi.check_read(self.tier.name, offset, nbytes, host_link=True)
-        out = np.empty(nbytes, dtype=np.uint8)
         first = offset // CACHELINE
         last = (offset + nbytes - 1) // CACHELINE
-        pos = 0
-        for line in range(first, last + 1):
-            lo = max(offset, line * CACHELINE)
-            hi = min(offset + nbytes, (line + 1) * CACHELINE)
-            cached = self._cache.get(line)
-            if cached is None:
-                cached = self.tier.buf[line * CACHELINE : (line + 1) * CACHELINE].copy()
-                self._cache[line] = cached
-                self.stats["pool_reads"] += 1
-            else:
-                self.stats["cached_reads"] += 1
-            out[pos : pos + hi - lo] = cached[lo - line * CACHELINE : hi - line * CACHELINE]
-            pos += hi - lo
+        if self._cached is None:
+            self._cached = np.zeros(-(-self.tier.capacity // CACHELINE), bool)
+            self._shadow = np.zeros(self.tier.capacity, np.uint8)
+        hit = self._cached[first : last + 1]
+        n_hit = int(np.count_nonzero(hit))
+        if n_hit < hit.size:
+            # fill every missing run of lines from the pool in one copy each
+            edges = np.diff(np.concatenate(([0], (~hit).view(np.int8), [0])))
+            for lo, hi in zip(np.flatnonzero(edges == 1), np.flatnonzero(edges == -1)):
+                a, b = (first + lo) * CACHELINE, (first + hi) * CACHELINE
+                self._shadow[a:b] = self.tier.buf[a:b]
+            hit[:] = True
+        self.stats["pool_reads"] += hit.size - n_hit
+        self.stats["cached_reads"] += n_hit
+        out = self._shadow[offset : offset + nbytes].copy()
         self.stats["bytes_read"] += nbytes
         if fi is not None:
             # poison the returned copy only — the line cache and the pool
@@ -346,15 +351,14 @@ class HostView:
         """clflushopt over [offset, offset+nbytes): drop cached lines."""
         first = offset // CACHELINE
         last = (offset + nbytes - 1) // CACHELINE
-        n = 0
-        for line in range(first, last + 1):
-            if self._cache.pop(line, None) is not None:
-                n += 1
+        if self._cached is not None:
+            self._cached[first : last + 1] = False
         self.stats["flushed_lines"] += last - first + 1
         self.ledger.add("clflush", (last - first + 1) * CLFLUSH_PER_LINE_S)
 
     def drop_all(self) -> None:
-        self._cache.clear()
+        if self._cached is not None:
+            self._cached[:] = False
 
 
 class HierarchicalPool:

@@ -66,7 +66,14 @@ ScatterFn = Callable[[np.ndarray, np.ndarray, np.ndarray], np.ndarray]
 
 
 class Instance:
-    """A restoring/running instance's guest address space + present bitmap."""
+    """A restoring/running instance's guest address space + present bitmap.
+
+    The guest memory is the host ``image``, or — when the scatter kernel
+    owns device memory (``FusedScatter(use_pallas=True)``, the TPU data
+    plane) — ``device_pages``: one donated device page array that every
+    install writes into, sending only the compact rows and their indices.
+    It starts zeroed, so zero-page installs only mark pages present, as on
+    the host.  ``present`` and the ledger stay on the host either way."""
 
     def __init__(self, image: StateImage, ledger: Optional[TimeLedger] = None,
                  scatter_fn: Optional[ScatterFn] = None,
@@ -74,7 +81,8 @@ class Instance:
         self.image = image
         self.present = np.zeros(image.total_pages, dtype=bool)
         self.ledger = ledger or TimeLedger()
-        self.scatter_fn = scatter_fn
+        self.device_pages = None
+        self.set_scatter(scatter_fn)
         self.clock = clock or REAL_CLOCK
         self.stats = {
             "pre_installed": 0,
@@ -89,8 +97,27 @@ class Instance:
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
 
+    def set_scatter(self, scatter_fn: Optional[ScatterFn]) -> None:
+        """Route installs through ``scatter_fn``; a scatter that allocates
+        device memory (``new_memory``) moves the guest memory there."""
+        self.scatter_fn = scatter_fn
+        new_memory = getattr(scatter_fn, "new_memory", None)
+        if self.device_pages is None and new_memory is not None:
+            self.device_pages = new_memory(self.image.total_pages, PAGE_SIZE)
+            assert self.device_pages is None or not self.present.any(), \
+                "guest memory moved to the device after installs began"
+
+    def image_bytes(self) -> np.ndarray:
+        """The guest memory as host bytes: the host image, or ONE download
+        of the device page array."""
+        if self.device_pages is None:
+            return self.image.buf
+        return np.asarray(self.device_pages).view(np.uint8).reshape(-1)
+
     # -- uffd analogues ------------------------------------------------------
     def uffd_copy(self, page: int, src: np.ndarray) -> bool:
+        if self.device_pages is not None:
+            return self.uffd_copy_batch(np.array([page]), src) == 1
         with self._cv:
             if self.present[page]:
                 return False
@@ -114,13 +141,24 @@ class Instance:
             if not todo.any():
                 return 0
             sel = pages[todo]
-            pm = self.image.pages_matrix()
-            if self.scatter_fn is not None:
-                out = np.asarray(self.scatter_fn(pm, mat[todo], sel))
-                if out is not pm:          # functional (jax) scatter returned a copy
-                    pm[sel] = out[sel]
+            if self.device_pages is not None:
+                try:
+                    self.device_pages = self.scatter_fn(
+                        self.device_pages, mat[todo], sel)
+                except RuntimeError as err:
+                    # a failed verify still installed into (and donated)
+                    # the page array: keep the one it returned
+                    if getattr(err, "dest", None) is not None:
+                        self.device_pages = err.dest
+                    raise
             else:
-                pm[sel] = mat[todo]
+                pm = self.image.pages_matrix()
+                if self.scatter_fn is not None:
+                    out = np.asarray(self.scatter_fn(pm, mat[todo], sel))
+                    if out is not pm:      # functional (jax) scatter returned a copy
+                        pm[sel] = out[sel]
+                else:
+                    pm[sel] = mat[todo]
             self.present[sel] = True
             n = int(sel.size)
             n_ranges = int(1 + np.count_nonzero(np.diff(sel) != 1))
@@ -378,7 +416,7 @@ class RestoreEngine:
                      if hasattr(scatter_fn, "bind_checksums") else None)
             if table is not None:
                 scatter_fn = scatter_fn.bind_checksums(table)
-            self.instance.scatter_fn = scatter_fn
+            self.instance.set_scatter(scatter_fn)
         if clock is not None:
             # route the engine's clock to the instance too: page waits
             # (wait_present) are the engine's only timed behaviour

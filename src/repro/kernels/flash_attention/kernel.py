@@ -27,9 +27,6 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 LANES = 128
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
                   *, causal: bool, scale: float, bq: int, bk: int,
@@ -124,7 +121,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, LANES), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,34 +1,24 @@
-"""jit'd wrapper for page checksums with CPU fallback."""
-import jax
+"""Wrapper for page checksums: the Pallas kernel or the jnp oracle."""
 import jax.numpy as jnp
 import numpy as np
 
+from ..layout import page_tiles, page_words, weight_tile
 from .kernel import page_checksum_pallas
 from .ref import page_checksum_ref, poly_weights
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def page_checksum(pages_bytes, *, block_pages: int = 256,
-                  use_pallas: bool | None = None,
-                  interpret: bool | None = None) -> jnp.ndarray:
-    """pages_bytes: (n_pages, page_bytes) uint8 -> uint32[n_pages]."""
-    arr = np.ascontiguousarray(pages_bytes)
-    pages_u32 = jnp.asarray(arr.view(np.uint32).reshape(arr.shape[0], -1))
-    w = poly_weights(pages_u32.shape[1])
-    if use_pallas is None:
-        use_pallas = _on_tpu()
+                  use_pallas: bool = False,
+                  interpret: bool = False) -> jnp.ndarray:
+    """pages_bytes: (n_pages, page_bytes) -> uint32[n_pages]."""
+    tiles = page_tiles(pages_bytes)
+    n, rows, lanes = tiles.shape
     if not use_pallas:
-        return page_checksum_ref(pages_u32, w)
-    if interpret is None:
-        interpret = not _on_tpu()
-    n = pages_u32.shape[0]
+        return page_checksum_ref(jnp.asarray(page_words(tiles)),
+                                 poly_weights(rows * lanes))
     pad = (-n) % block_pages
     if pad:
-        pages_u32 = jnp.concatenate(
-            [pages_u32, jnp.zeros((pad, pages_u32.shape[1]), jnp.uint32)], axis=0
-        )
-    out = page_checksum_pallas(pages_u32, w, block_pages=block_pages, interpret=interpret)
+        tiles = np.concatenate([tiles, np.zeros((pad, rows, lanes), np.uint32)])
+    out = page_checksum_pallas(jnp.asarray(tiles), weight_tile(rows),
+                               block_pages=block_pages, interpret=interpret)
     return out[:n]

@@ -6,8 +6,9 @@ N-page sharded state image.  The page index list is **scalar-prefetched**
 ``idx[i+1]`` while page ``idx[i]`` is being written back — random-access
 reads become overlapped streaming.
 
-One grid step moves `rows_per_step` index-contiguous output rows; the input
-BlockSpec picks the source page per step via the prefetched index ref.
+One grid step moves one page tile (``kernels/layout.py``: a 4 KiB page is
+one (8, 128) uint32 tile); the input BlockSpec picks the source page per
+step via the prefetched index ref.
 """
 import functools
 
@@ -24,20 +25,20 @@ def _gather_kernel(idx_ref, pages_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def page_gather_pallas(pages: jnp.ndarray, indices: jnp.ndarray, *, interpret: bool = False):
-    """pages: (N, E); indices: int32[M] -> (M, E)."""
-    n, e = pages.shape
+    """pages: (N, rows, 128); indices: int32[M] -> (M, rows, 128)."""
+    n, rows, lanes = pages.shape
     (m,) = indices.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(m,),
         in_specs=[
-            pl.BlockSpec((1, e), lambda i, idx_ref: (idx_ref[i], 0)),
+            pl.BlockSpec((1, rows, lanes), lambda i, idx_ref: (idx_ref[i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, e), lambda i, idx_ref: (i, 0)),
+        out_specs=pl.BlockSpec((1, rows, lanes), lambda i, idx_ref: (i, 0, 0)),
     )
     return pl.pallas_call(
         _gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m, e), pages.dtype),
+        out_shape=jax.ShapeDtypeStruct((m, rows, lanes), pages.dtype),
         interpret=interpret,
     )(indices, pages)

@@ -1,26 +1,23 @@
-"""jit'd wrapper for page scatter with CPU fallback."""
-import jax
+"""Wrapper for page scatter: the Pallas kernel or the jnp oracle."""
 import jax.numpy as jnp
+import numpy as np
 
+from ..layout import page_tiles
 from .kernel import page_scatter_pallas
 from .ref import page_scatter_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def page_scatter(dest, compact, indices, *, use_pallas: bool | None = None,
-                 interpret: bool | None = None) -> jnp.ndarray:
-    dest = jnp.asarray(dest)
-    compact = jnp.asarray(compact)
-    indices = jnp.asarray(indices, dtype=jnp.int32)
+def page_scatter(dest, compact, indices, *, use_pallas: bool = False,
+                 interpret: bool = False):
+    """dest (N, E) with dest[indices[i]] = compact[i]; same dtype as dest."""
+    indices = np.asarray(indices, dtype=np.int32)
     if indices.shape[0] == 0:
         return dest
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     if not use_pallas:
-        return page_scatter_ref(dest, compact, indices)
-    if interpret is None:
-        interpret = not _on_tpu()
-    return page_scatter_pallas(dest, compact, indices, interpret=interpret)
+        return page_scatter_ref(jnp.asarray(dest), jnp.asarray(compact),
+                                jnp.asarray(indices))
+    host = np.asarray(dest)
+    out = page_scatter_pallas(jnp.asarray(page_tiles(host)),
+                              jnp.asarray(page_tiles(compact)),
+                              jnp.asarray(indices), interpret=interpret)
+    return np.asarray(out).view(host.dtype).reshape(host.shape)

@@ -11,8 +11,14 @@
                       checksum table, in which case every installed page is
                       verified in the same kernel invocation that installs it.
 
-CPU fallback is the numpy oracle (in-place, zero-copy for the serving path);
-``use_pallas=True`` with ``interpret=True`` runs the real kernels off-TPU.
+The backend is explicit and never falls back.  ``use_pallas=False`` runs the
+numpy oracle on host page matrices (the restore updates them in place);
+``use_pallas=True`` runs the Pallas kernels on device arrays, compiled, or in
+the interpreter where a test asks with ``interpret=True``.  A kernel-backed
+``FusedScatter`` owns the restoring instance's memory: one donated device
+page array that every install writes into.  :func:`default_publish_fn` and
+:func:`default_scatter_fn` decide the data plane once, from the backend: the
+compiled kernels on a TPU, the host path elsewhere.
 """
 
 from __future__ import annotations
@@ -24,13 +30,20 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..page_checksum.ref import poly_weights
+from ..backend import on_tpu
+from ..layout import LANES, page_tiles, page_words, weight_tile
 from .kernel import fused_publish_pallas, fused_restore_pallas
 from .ref import fused_publish_ref, fused_restore_ref
 
+# The publish kernel sweeps the image in slabs of this many pages (256 MiB
+# of 4 KiB pages): its compacted outputs are two slab-sized buffers, not two
+# image-sized ones, so a multi-GiB image publishes beside the HBM it lives in.
+SLAB_PAGES = 1 << 16
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+# The restore kernel installs batches of at most this many pages (the hot
+# chunk, ``RestoreEngine.HOT_CHUNK_PAGES``); longer batches (whole cold runs)
+# are split, so only the power-of-two batch sizes up to it ever compile.
+MAX_BATCH_PAGES = 256
 
 
 class ChecksumMismatchError(RuntimeError):
@@ -39,14 +52,17 @@ class ChecksumMismatchError(RuntimeError):
     ``bad_pages`` is the structured payload — a 1-D int64 array of the
     failing GUEST page indices — which the serving layer's checksum-repair
     path consumes (``RestoreEngine._install_verified``).  The message stays
-    human-readable and truncated no matter how many pages failed.
+    human-readable and truncated no matter how many pages failed.  ``dest``
+    is the page memory after the failed batch was installed: the kernel path
+    donates its input, so the caller keeps this array instead.
     """
 
     MAX_SHOWN = 8
 
-    def __init__(self, pages: np.ndarray):
+    def __init__(self, pages: np.ndarray, dest=None):
         self.bad_pages = np.atleast_1d(
             np.asarray(pages, dtype=np.int64)).reshape(-1)
+        self.dest = dest
         shown = self.bad_pages[: self.MAX_SHOWN].tolist()
         extra = self.bad_pages.size - len(shown)
         super().__init__(
@@ -69,52 +85,56 @@ class FusedPublishResult:
     cold: np.ndarray          # uint8[n_cold, page_bytes], ascending page order
 
 
-def _as_u32(pages_bytes: np.ndarray) -> np.ndarray:
-    arr = np.ascontiguousarray(pages_bytes)
-    if arr.dtype != np.uint8:
-        arr = arr.view(np.uint8)
-    lanes = arr.shape[1] // 4 if arr.ndim == 2 else 0
-    return arr.view(np.uint32).reshape(arr.shape[0], lanes)
+def _pad_rows(a: np.ndarray, n: int) -> np.ndarray:
+    """``a`` with zero rows appended up to ``n`` rows."""
+    if a.shape[0] == n:
+        return a
+    return np.concatenate([a, np.zeros((n - a.shape[0],) + a.shape[1:], a.dtype)])
 
 
 def fused_publish(pages_bytes: np.ndarray, ws_mask: np.ndarray, *,
-                  block_pages: int = 256, use_pallas: Optional[bool] = None,
-                  interpret: Optional[bool] = None) -> FusedPublishResult:
+                  block_pages: int = 256, use_pallas: bool = False,
+                  interpret: bool = False) -> FusedPublishResult:
     """pages_bytes: (N, page_bytes) uint8; ws_mask: bool[N] working set."""
-    u32 = _as_u32(pages_bytes)
-    n, e = u32.shape
-    page_bytes = e * 4
+    tiles = page_tiles(pages_bytes)
+    n, rows, _ = tiles.shape
+    page_bytes = rows * LANES * 4
     ws = np.asarray(ws_mask, dtype=bool)
-    if use_pallas is None:
-        use_pallas = _on_tpu()
     if n == 0 or not use_pallas:
-        zero, csum, hot, cold = fused_publish_ref(u32, ws)
+        zero, csum, hot, cold = fused_publish_ref(page_words(tiles), ws)
         return FusedPublishResult(zero, csum,
                                   hot.view(np.uint8), cold.view(np.uint8))
-    if interpret is None:
-        interpret = not _on_tpu()
-    pad = (-n) % block_pages
-    u32_p, ws_p = u32, ws
-    if pad:
-        # zero filler: padded rows read as zero pages, so they are excluded
-        # from both compactions; the bitmap/checksum tails are sliced off
-        u32_p = np.concatenate([u32, np.zeros((pad, e), np.uint32)], axis=0)
-        ws_p = np.concatenate([ws, np.zeros(pad, bool)])
-    zero_i32, csum, hot, cold, counts = fused_publish_pallas(
-        jnp.asarray(u32_p), jnp.asarray(ws_p.astype(np.int32)),
-        poly_weights(e), block_pages=block_pages, interpret=interpret)
-    counts = np.asarray(counts)
-    n_hot, n_cold = int(counts[0]), int(counts[1])
-    result = FusedPublishResult(
-        np.asarray(zero_i32[:n]) != 0,
-        np.asarray(csum[:n]),
-        np.asarray(hot[:n_hot]).view(np.uint8).reshape(n_hot, page_bytes),
-        np.asarray(cold[:n_cold]).view(np.uint8).reshape(n_cold, page_bytes),
-    )
-    nz = ~result.zero_bitmap
+    # every slab has one shape (one compile); the last is padded with zero
+    # pages, which both compactions skip and whose flags are sliced off
+    slab = min(SLAB_PAGES, -(-n // block_pages) * block_pages)
+    assert slab % block_pages == 0, (slab, block_pages)
+    zero = np.empty(n, bool)
+    csum = np.empty(n, np.uint32)
+    # compacted outputs are written as slabs come back; untouched tails of
+    # these n-row buffers are never committed to host memory
+    hot = np.empty((n, page_bytes), np.uint8)
+    cold = np.empty((n, page_bytes), np.uint8)
+    n_hot = n_cold = 0
+    w = weight_tile(rows)
+    for lo in range(0, n, slab):
+        part = tiles[lo : lo + slab]
+        k = part.shape[0]
+        z, c, h, cd, counts = fused_publish_pallas(
+            jnp.asarray(_pad_rows(part, slab)),
+            jnp.asarray(_pad_rows(ws[lo : lo + slab].astype(np.int32), slab)),
+            w, block_pages=block_pages, interpret=interpret)
+        kh, kc = (int(v) for v in np.asarray(counts))
+        zero[lo : lo + k] = np.asarray(z)[:k] != 0
+        csum[lo : lo + k] = np.asarray(c)[:k]
+        if kh:
+            hot[n_hot : n_hot + kh] = np.asarray(h)[:kh].view(np.uint8).reshape(kh, -1)
+        if kc:
+            cold[n_cold : n_cold + kc] = np.asarray(cd)[:kc].view(np.uint8).reshape(kc, -1)
+        n_hot, n_cold = n_hot + kh, n_cold + kc
+    nz = ~zero
     assert n_hot == int(np.count_nonzero(nz & ws)), "hot count drifted"
     assert n_cold == int(np.count_nonzero(nz & ~ws)), "cold count drifted"
-    return result
+    return FusedPublishResult(zero, csum, hot[:n_hot], cold[:n_cold])
 
 
 # build_snapshot's publish_fn seam: (pages_matrix uint8[N, PAGE_SIZE],
@@ -122,9 +142,8 @@ def fused_publish(pages_bytes: np.ndarray, ws_mask: np.ndarray, *,
 PublishFn = Callable[[np.ndarray, np.ndarray], FusedPublishResult]
 
 
-def make_fused_publish_fn(*, block_pages: int = 256,
-                          use_pallas: Optional[bool] = None,
-                          interpret: Optional[bool] = None) -> PublishFn:
+def make_fused_publish_fn(*, block_pages: int = 256, use_pallas: bool = False,
+                          interpret: bool = False) -> PublishFn:
     def publish_fn(pages_matrix: np.ndarray, ws: np.ndarray) -> FusedPublishResult:
         return fused_publish(pages_matrix, ws, block_pages=block_pages,
                              use_pallas=use_pallas, interpret=interpret)
@@ -132,15 +151,26 @@ def make_fused_publish_fn(*, block_pages: int = 256,
     return publish_fn
 
 
-def fused_restore(dest: np.ndarray, compact: np.ndarray, indices: np.ndarray,
+def _bucket(m: int) -> int:
+    """Next power of two: batch shapes the restore kernel compiles for."""
+    return 1 << max(0, int(m) - 1).bit_length()
+
+
+def fused_restore(dest, compact: np.ndarray, indices: np.ndarray,
                   *, src_indices: Optional[np.ndarray] = None,
                   expected_csums: Optional[np.ndarray] = None,
-                  use_pallas: Optional[bool] = None,
-                  interpret: Optional[bool] = None):
+                  use_pallas: bool = False, interpret: bool = False):
     """Install ``compact[src_indices[i]]`` at ``dest[indices[i]]`` and return
     ``(dest', csums uint32[M])``; raises :class:`ChecksumMismatchError` when
-    ``expected_csums`` (aligned with ``indices``) disagree.  The CPU path
-    updates ``dest`` in place and returns the same object."""
+    ``expected_csums`` (aligned with ``indices``) disagree.
+
+    Host path: ``dest`` is a uint8 page matrix, updated in place and
+    returned.  Kernel path: ``dest`` is the device page array ``(N, R, 128)``
+    uint32 (a host matrix is uploaded first); it is donated and the new
+    array returned.  Only the compact rows and their indices cross to the
+    device, in batches of at most :data:`MAX_BATCH_PAGES`, each padded to a
+    power of two by repeating its last (source, destination) pair, which
+    rewrites the same page."""
     indices = np.asarray(indices, dtype=np.int32)
     m = indices.shape[0]
     if src_indices is None:
@@ -149,28 +179,32 @@ def fused_restore(dest: np.ndarray, compact: np.ndarray, indices: np.ndarray,
         src_indices = np.asarray(src_indices, dtype=np.int32)
     if m == 0:
         return dest, np.zeros(0, np.uint32)
-    if use_pallas is None:
-        use_pallas = _on_tpu()
+    chunk = page_tiles(compact)
     if not use_pallas:
-        dest_u32 = _as_u32(dest) if isinstance(dest, np.ndarray) else _as_u32(np.asarray(dest))
-        out_u32, csums = fused_restore_ref(dest_u32, _as_u32(compact),
-                                           src_indices, indices)
-        out = dest if isinstance(dest, np.ndarray) else out_u32.view(np.uint8)
+        out = dest if isinstance(dest, np.ndarray) else np.asarray(dest).copy()
+        _, csums = fused_restore_ref(page_words(out), page_words(chunk),
+                                     src_indices, indices)
     else:
-        if interpret is None:
-            interpret = not _on_tpu()
-        e = _as_u32(compact).shape[1]
-        out_u32, csums = fused_restore_pallas(
-            jnp.asarray(_as_u32(np.asarray(dest))), jnp.asarray(_as_u32(compact)),
-            jnp.asarray(src_indices), jnp.asarray(indices),
-            poly_weights(e), interpret=interpret)
-        out = np.asarray(out_u32).view(np.uint8).reshape(np.asarray(dest).shape)
-        csums = np.asarray(csums)
+        out = (jnp.asarray(page_tiles(dest)) if isinstance(dest, np.ndarray)
+               else dest)
+        w = weight_tile(chunk.shape[1])
+        parts = []
+        for lo in range(0, m, MAX_BATCH_PAGES):
+            src = src_indices[lo : lo + MAX_BATCH_PAGES]
+            dst = indices[lo : lo + MAX_BATCH_PAGES]
+            pad = _bucket(dst.size) - dst.size
+            rows = chunk[np.concatenate([src, np.repeat(src[-1:], pad)])]
+            out, c = fused_restore_pallas(
+                out, jnp.asarray(rows), jnp.arange(rows.shape[0], dtype=jnp.int32),
+                jnp.asarray(np.concatenate([dst, np.repeat(dst[-1:], pad)])),
+                w, interpret=interpret)
+            parts.append((c, dst.size))
+        csums = np.concatenate([np.asarray(c)[:k] for c, k in parts])
     if expected_csums is not None:
-        bad = np.asarray(csums) != np.asarray(expected_csums, dtype=np.uint32)
+        bad = csums != np.asarray(expected_csums, dtype=np.uint32)
         if bad.any():
-            raise ChecksumMismatchError(indices[bad])
-    return out, np.asarray(csums)
+            raise ChecksumMismatchError(indices[bad], dest=out)
+    return out, csums
 
 
 class FusedScatter:
@@ -184,11 +218,11 @@ class FusedScatter:
     reader's regions carry one), every batch is verified against
     ``table[indices]`` inside the same fused invocation that installs it.
     Bound copies share the template's ``stats`` dict so fan-out totals stay
-    observable in one place.
+    observable in one place.  With ``use_pallas`` the instance's memory is
+    the device array :meth:`new_memory` allocates.
     """
 
-    def __init__(self, *, use_pallas: Optional[bool] = None,
-                 interpret: Optional[bool] = None,
+    def __init__(self, *, use_pallas: bool = False, interpret: bool = False,
                  expected: Optional[np.ndarray] = None,
                  stats: Optional[dict] = None):
         self.use_pallas = use_pallas
@@ -201,8 +235,15 @@ class FusedScatter:
         return FusedScatter(use_pallas=self.use_pallas, interpret=self.interpret,
                             expected=table, stats=self.stats)
 
-    def __call__(self, dest: np.ndarray, compact: np.ndarray,
-                 indices: np.ndarray) -> np.ndarray:
+    def new_memory(self, total_pages: int, page_bytes: int) -> Optional[jax.Array]:
+        """A zeroed device page array for a restoring instance, or None when
+        installs run on the host (the instance keeps its numpy image)."""
+        if not self.use_pallas:
+            return None
+        return jnp.zeros((total_pages, page_bytes // (4 * LANES), LANES),
+                         jnp.uint32)
+
+    def __call__(self, dest, compact: np.ndarray, indices: np.ndarray):
         idx = np.asarray(indices)
         exp = self.expected[idx] if self.expected is not None else None
         out, _csums = fused_restore(dest, compact, idx, expected_csums=exp,
@@ -213,3 +254,15 @@ class FusedScatter:
         if exp is not None:
             self.stats["pages_verified"] += int(idx.size)
         return out
+
+
+def default_publish_fn() -> Optional[PublishFn]:
+    """The publish data plane: the compiled kernel on a TPU, else None (the
+    host pipeline in ``build_snapshot``)."""
+    return make_fused_publish_fn(use_pallas=True) if on_tpu() else None
+
+
+def default_scatter_fn() -> Optional[FusedScatter]:
+    """The restore data plane: the compiled kernel over HBM-resident
+    instance memory on a TPU, else None (numpy installs)."""
+    return FusedScatter(use_pallas=True) if on_tpu() else None

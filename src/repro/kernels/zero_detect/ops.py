@@ -1,33 +1,28 @@
-"""jit'd wrapper: pad-to-block, dispatch Pallas on TPU / interpret elsewhere."""
-import jax
+"""Wrapper: page tiles, pad-to-block, then the Pallas kernel or the oracle."""
 import jax.numpy as jnp
 import numpy as np
 
+from ..layout import page_tiles, page_words
 from .kernel import zero_detect_pallas
 from .ref import zero_detect_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def zero_detect(pages, *, block_pages: int = 256, use_pallas: bool = False,
+                interpret: bool = False) -> jnp.ndarray:
+    """int32[n_pages] zero-page bitmap (1 = every bit of the page is zero).
 
-
-def zero_detect(pages, *, block_pages: int = 256, use_pallas: bool | None = None,
-                interpret: bool | None = None) -> jnp.ndarray:
-    """int32[n_pages] zero-page bitmap; pads ragged tails with a nonzero
-    sentinel so padding never reports zero."""
-    pages = jnp.asarray(pages)
-    n = pages.shape[0]
-    if use_pallas is None:
-        use_pallas = _on_tpu()
+    ``use_pallas`` runs the kernel (compiled, or in the interpreter with
+    ``interpret``); ragged tails are padded with a nonzero sentinel so
+    padding never reports zero."""
+    tiles = page_tiles(pages)
+    n = tiles.shape[0]
     if not use_pallas:
-        return zero_detect_ref(pages)
-    if interpret is None:
-        interpret = not _on_tpu()
+        return zero_detect_ref(jnp.asarray(page_words(tiles)))
     pad = (-n) % block_pages
     if pad:
-        filler = jnp.ones((pad, pages.shape[1]), dtype=pages.dtype)
-        pages = jnp.concatenate([pages, filler], axis=0)
-    out = zero_detect_pallas(pages, block_pages=block_pages, interpret=interpret)
+        tiles = np.concatenate([tiles, np.ones((pad,) + tiles.shape[1:], np.uint32)])
+    out = zero_detect_pallas(jnp.asarray(tiles), block_pages=block_pages,
+                             interpret=interpret)
     return out[:n]
 
 

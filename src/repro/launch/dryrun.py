@@ -34,6 +34,7 @@ from ..sharding.partition import (
 )
 from ..train.trainstep import TrainState, make_train_step
 from ..train.optimizer import AdamWState
+from .compile_cache import enable_compile_cache
 from .mesh import make_production_mesh
 
 OUT_DIR = Path(__file__).resolve().parents[3] / "experiments" / "dryrun"
@@ -214,4 +215,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

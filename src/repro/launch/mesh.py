@@ -14,16 +14,11 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    """jax.sharding.AxisType landed after 0.4.x; Auto is the default there."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kw(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -32,4 +27,4 @@ def make_host_mesh(data: int = 1, model: int = 1):
     data = min(data, n)
     model = max(1, min(model, n // max(1, data)))
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_types_kw(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -15,6 +15,7 @@ from ..core import HierarchicalPool, PoolMaster
 from ..data.pipeline import DataConfig, SyntheticLMData
 from ..models.model_zoo import build
 from ..train.loop import LoopConfig, Trainer
+from .compile_cache import enable_compile_cache
 
 
 def main(argv=None) -> int:
@@ -58,4 +59,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

@@ -681,4 +681,4 @@ def verify_restore_correctness(pool: HierarchicalPool, reader: SnapshotReader,
     eng = RestoreEngine(reader, inst, rdma_engine=None)
     eng.pre_install_hot()
     eng.install_all_sync()
-    return bool(np.array_equal(inst.image.buf, spec.image.buf))
+    return bool(np.array_equal(inst.image_bytes(), spec.image.buf))

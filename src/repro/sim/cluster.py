@@ -660,7 +660,7 @@ class SimCluster:
             self.events.append(f"degraded_restore:{host}:{name}")
             yield "restore:degraded"
         canonical = self.content[name][rec.version]
-        if not inst.all_present() or not np.array_equal(inst.image.buf, canonical.buf):
+        if not inst.all_present() or not np.array_equal(inst.image_bytes(), canonical.buf):
             raise InvariantViolation(
                 f"[seed={self.seed} step={self.step_no}] {host}: restore of "
                 f"{name!r} v{rec.version} is not bit-identical")
@@ -715,7 +715,7 @@ class SimCluster:
                 reader.split_cold_extent(rank0, en, payload))
             yield "restore:predicted_cold"
         canonical = self.content[name][rec.version]
-        if not inst.all_present() or not np.array_equal(inst.image.buf,
+        if not inst.all_present() or not np.array_equal(inst.image_bytes(),
                                                         canonical.buf):
             raise InvariantViolation(
                 f"[seed={self.seed} step={self.step_no}] {host}: predicted-"

@@ -125,8 +125,7 @@ class TestServing:
         model = build(TINY)
         params = model.init(jax.random.PRNGKey(1))
         pool, master, orch = make_stack()
-        save_checkpoint(master, "srv", {"params": params}, step=0,
-                        working_set=None)
+        save_checkpoint(master, "srv", {"params": params}, step=0)
         sp = SkeletonPool(TINY, batch=1, max_len=48, target_size=1, background=False)
         out = restore_server(orch, "srv", sp.claim(), {"params": params})
         inst = out["instance"]

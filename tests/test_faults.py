@@ -426,7 +426,12 @@ def test_zero_fault_schedule_leaves_ledger_byte_identical(fill_seed):
 # -- checksum repair ----------------------------------------------------------
 
 class TestChecksumRepair:
-    def test_poisoned_page_is_repaired_from_home_tier(self):
+    @pytest.mark.parametrize("memory", ["host", "device"])
+    def test_poisoned_page_is_repaired_from_home_tier(self, memory):
+        """A poisoned read fails the fused verify and is re-read clean —
+        whether the instance's memory is a host image or a device page
+        array (the kernel in the interpreter; a failed verify has already
+        installed into, and donated, that array)."""
         img, pool, borrow = publish_stack(fused=True)
         probe = SnapshotReader(borrow.regions, pool.host_view("probe"),
                                pool.rdma)
@@ -435,10 +440,12 @@ class TestChecksumRepair:
         inj = FaultInjector(seed=3).poison_reads("cxl", 1, lo=off,
                                                  hi=off + PAGE_SIZE)
         pool.attach_fault_injector(inj)
-        view, reader, inst, engine = run_restore(
-            img, pool, borrow, scatter_fn=FusedScatter(use_pallas=False))
+        scatter = FusedScatter(use_pallas=memory == "device",
+                               interpret=memory == "device")
+        view, reader, inst, engine = run_restore(img, pool, borrow,
+                                                 scatter_fn=scatter)
         assert inst.all_present()
-        np.testing.assert_array_equal(inst.image.buf, img.buf)
+        np.testing.assert_array_equal(inst.image_bytes(), img.buf)
         assert inj.stats["injected_poison"] == 1
         assert engine.repair_stats["checksum_mismatches"] == 1
         assert engine.repair_stats["checksum_repairs"] == 1

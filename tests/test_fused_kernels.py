@@ -7,6 +7,7 @@ the dedup ``hash_fn`` seam, the pluggable zero-scan backend, and the
 publish→restore checksum-verification loop end-to-end.
 """
 import dataclasses
+import functools
 
 import numpy as np
 import pytest
@@ -23,6 +24,8 @@ from repro.core.pagestore import (
     pallas_zero_scan,
     set_zero_scan_backend,
 )
+from repro.checkpoint.ckpt import restore_checkpoint, save_checkpoint
+from repro.core.serving import Instance, RestoreEngine
 from repro.core.snapshot import SnapshotReader, build_snapshot
 from repro.kernels import (
     FusedScatter,
@@ -34,6 +37,7 @@ from repro.kernels import (
     page_scatter,
     zero_detect,
 )
+from repro.kernels.snapshot_fuse import ops as snapshot_fuse_ops
 from repro.kernels.snapshot_fuse.ops import ChecksumMismatchError
 
 INTERP = {"use_pallas": True, "interpret": True}
@@ -55,6 +59,19 @@ class TestFusedPublish:
     def test_interpret_matches_oracle(self, n):
         """Odd counts and block tails (block_pages=8) vs the numpy ref."""
         pages, ws = _pages(n, seed=n)
+        got = fused_publish(pages, ws, block_pages=8, **INTERP)
+        want = fused_publish(pages, ws, use_pallas=False)
+        np.testing.assert_array_equal(got.zero_bitmap, want.zero_bitmap)
+        np.testing.assert_array_equal(got.checksums, want.checksums)
+        np.testing.assert_array_equal(got.hot, want.hot)
+        np.testing.assert_array_equal(got.cold, want.cold)
+
+    @pytest.mark.parametrize("n", [37, 64])
+    def test_slab_sweep_matches_oracle(self, n, monkeypatch):
+        """An image swept in several slabs (the last one padded) carries its
+        hot/cold counts across slabs: same outputs as the numpy ref."""
+        monkeypatch.setattr(snapshot_fuse_ops, "SLAB_PAGES", 16)
+        pages, ws = _pages(n, seed=n + 1)
         got = fused_publish(pages, ws, block_pages=8, **INTERP)
         want = fused_publish(pages, ws, use_pallas=False)
         np.testing.assert_array_equal(got.zero_bitmap, want.zero_bitmap)
@@ -130,9 +147,36 @@ class TestFusedRestore:
             np.zeros((n, PAGE_SIZE // 4), np.uint32), g, dst, **INTERP))
 
         out, csums = fused_restore(dest, chunk, dst, src_indices=src, **INTERP)
-        np.testing.assert_array_equal(
-            np.asarray(out).reshape(n, PAGE_SIZE).view(np.uint32), want)
+        np.testing.assert_array_equal(np.asarray(out).reshape(n, -1), want)
         np.testing.assert_array_equal(csums, cs)
+
+    @pytest.mark.parametrize("bad_page", [None, 17])
+    def test_long_batch_splits_like_one(self, bad_page, monkeypatch):
+        """A batch longer than MAX_BATCH_PAGES installs in pieces (the last
+        padded) with the same bytes and checksums as the oracle, and a bad
+        page in a later piece is still reported by its guest index."""
+        monkeypatch.setattr(snapshot_fuse_ops, "MAX_BATCH_PAGES", 8)
+        rng = np.random.default_rng(11)
+        chunk = rng.integers(0, 256, size=(21, PAGE_SIZE), dtype=np.uint8)
+        dst = np.sort(rng.choice(40, size=21, replace=False)).astype(np.int32)
+        src = rng.permutation(21).astype(np.int32)
+        want, want_cs = fused_restore(np.zeros((40, PAGE_SIZE), np.uint8),
+                                      chunk, dst, src_indices=src,
+                                      use_pallas=False)
+        exp = want_cs.copy()
+        if bad_page is not None:
+            exp[bad_page] ^= 1
+        try:
+            out, csums = fused_restore(np.zeros((40, PAGE_SIZE), np.uint8),
+                                       chunk, dst, src_indices=src,
+                                       expected_csums=exp, **INTERP)
+            assert bad_page is None
+            np.testing.assert_array_equal(csums, want_cs)
+        except ChecksumMismatchError as err:
+            assert err.bad_pages.tolist() == [dst[bad_page]]
+            out = err.dest
+        np.testing.assert_array_equal(
+            np.asarray(out).reshape(40, -1).view(np.uint8), want)
 
     def test_cpu_path_in_place(self):
         chunk, _ = _pages(6, seed=5, zero_every=0)
@@ -194,11 +238,21 @@ def _image(seed=11):
     })
 
 
+def _scatter(memory):
+    """The host oracle, or the kernel (in the interpreter) over a
+    device-resident page array — the TPU data plane's memory layout."""
+    if memory == "device":
+        return FusedScatter(use_pallas=True, interpret=True)
+    return FusedScatter(use_pallas=False)
+
+
 class TestEndToEnd:
+    @pytest.mark.parametrize("memory", ["host", "device"])
     @pytest.mark.parametrize("dedup", [False, True])
-    def test_publish_restore_bit_identical_and_verified(self, dedup):
+    def test_publish_restore_bit_identical_and_verified(self, dedup, memory):
         """Fused publish (master-wide publish_fn) → node-server restore with
-        the fused verified scatter: bytes identical, every page checked."""
+        the fused verified scatter: bytes identical, every page checked —
+        into a host image, or into one device page array."""
         img = _image()
         ws = list(range(0, img.total_pages, 3))
         pool = HierarchicalPool(
@@ -211,14 +265,17 @@ class TestEndToEnd:
         assert getattr(regions, "page_checksums", None) is not None
         if dedup:
             assert pool.dedup_cxl.stats["unique"] > 0
-        orch = Orchestrator("hostA", pool, catalog,
-                            scatter_fn=FusedScatter(use_pallas=False))
+        orch = Orchestrator("hostA", pool, catalog, scatter_fn=_scatter(memory))
         ri = orch.restore("model", pre_install=True)
         assert ri is not None
         ri.engine.install_all_sync()
         assert ri.instance.all_present()
-        np.testing.assert_array_equal(ri.instance.image.buf, img.buf)
-        assert ri.instance.scatter_fn.stats["pages_verified"] > 0
+        np.testing.assert_array_equal(ri.instance.image_bytes(), img.buf)
+        assert (ri.instance.device_pages is not None) == (memory == "device")
+        if memory == "device":
+            assert not ri.instance.image.buf.any()   # the host image is unused
+        stats = ri.instance.scatter_fn.stats
+        assert stats["pages_verified"] == ri.instance.stats["uffd_copies"] > 0
         ri.shutdown()
         orch.close()
 
@@ -264,11 +321,62 @@ class TestZeroScanBackend:
     def test_parity_and_install(self):
         img = _image(seed=14)
         want = numpy_zero_scan(img.pages_matrix())
-        np.testing.assert_array_equal(
-            img.zero_page_bitmap(backend=pallas_zero_scan), want)
-        prev = set_zero_scan_backend(pallas_zero_scan)
+        scan = functools.partial(pallas_zero_scan, interpret=True)
+        np.testing.assert_array_equal(img.zero_page_bitmap(backend=scan), want)
+        prev = set_zero_scan_backend(scan)
         try:
             np.testing.assert_array_equal(img.zero_page_bitmap(), want)
         finally:
             set_zero_scan_backend(prev)
         np.testing.assert_array_equal(img.zero_page_bitmap(), want)
+
+
+class TestDeviceResidentInstance:
+    """The TPU data plane's instance memory, exercised off-TPU with the
+    kernels in the interpreter: every install path writes into one device
+    page array and the served state is built from it."""
+
+    @pytest.mark.parametrize("use_batch", [True, False])
+    def test_every_install_path_lands_in_device_memory(self, use_batch):
+        img = _image(seed=15)
+        ws = list(range(0, img.total_pages, 2))   # hot, cold and zero pages
+        pool = HierarchicalPool(cxl_capacity=64 << 20, rdma_capacity=64 << 20)
+        master = PoolMaster(pool, Catalog(),
+                            publish_fn=make_fused_publish_fn(use_pallas=False))
+        regions = master.publish("m", img, ws)
+        assert regions.n_hot and regions.n_cold and regions.n_zero
+        reader = SnapshotReader(regions, pool.host_view("h"), pool.rdma)
+        inst = Instance(StateImage.empty_like(img.manifest))
+        engine = RestoreEngine(reader, inst, None, scatter_fn=_scatter("device"))
+        engine.install_all_sync(use_batch=use_batch)
+        assert inst.all_present()
+        np.testing.assert_array_equal(inst.image_bytes(), img.buf)
+        assert inst.device_pages.shape == (img.total_pages, 8, 128)
+        assert not inst.image.buf.any()
+
+    def test_checkpoint_state_built_from_device_pages(self):
+        """restore_checkpoint slices and bitcasts each page-aligned extent of
+        the device page array: same leaves, dtypes and bits as the host
+        path, including sub-word dtypes and ragged tails."""
+        import jax.numpy as jnp
+
+        rng = np.random.default_rng(16)
+        state = {"w": jnp.asarray(rng.standard_normal((33, 70)), jnp.bfloat16),
+                 "b": np.arange(7, dtype=np.int32),
+                 "q": rng.integers(0, 255, (5, 3), dtype=np.uint8),
+                 "step": np.int32(9)}
+        out = {}
+        for memory in ("host", "device"):
+            pool = HierarchicalPool(cxl_capacity=64 << 20,
+                                    rdma_capacity=64 << 20)
+            master = PoolMaster(pool, Catalog())
+            save_checkpoint(master, "ck", state, step=1)
+            orch = Orchestrator("h", pool, master.catalog,
+                                scatter_fn=_scatter(memory))
+            out[memory], _ = restore_checkpoint(orch, "ck", state)
+            orch.close()
+        for name, want in state.items():
+            for memory in ("host", "device"):
+                got = np.asarray(out[memory][name])
+                assert got.dtype == np.asarray(want).dtype, (name, memory)
+                np.testing.assert_array_equal(got, np.asarray(want))
