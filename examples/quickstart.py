@@ -43,8 +43,8 @@ def main():
         orch, trainer.loop_cfg.ckpt_name,
         {"params": state.params, "opt": state.opt})
     print(f"restored step={stats['meta']['step']} "
-          f"time-to-hot={stats['time_to_hot_s']*1e3:.1f}ms "
-          f"time-to-full={stats['time_to_full_s']*1e3:.1f}ms")
+          f"borrow+hot installs {stats['time_to_hot_s']*1e3:.1f}ms, "
+          f"all installs {stats['time_to_full_s']*1e3:.1f}ms (host wall time)")
     for a, b in zip(jax.tree.leaves(state.params), jax.tree.leaves(restored["params"])):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     print("restored params are bit-identical ✓")

@@ -34,6 +34,7 @@ from .pool import HierarchicalPool, TimeLedger
 from .prefetch_model import PrefetchPolicy, resolve_policy
 from .serving import AsyncRDMAEngine, BufferPool, Instance, RestoreEngine
 from .snapshot import SnapshotReader
+from ..spans import RESTORE_BORROW, span
 
 
 @dataclasses.dataclass
@@ -130,47 +131,48 @@ class Orchestrator:
         extents are additionally streamed in the background in
         ``prefetch_policy`` order (default: the orchestrator's policy, i.e.
         snapshot layout) while demand faults retain priority (§3.4)."""
-        borrow = self.catalog.borrow(name)
-        if borrow is None or borrow.regions is None:
-            with self._lock:
-                self.stats["cold_starts"] += 1
-            return None
+        with span(RESTORE_BORROW):
+            borrow = self.catalog.borrow(name)
+            if borrow is None or borrow.regions is None:
+                with self._lock:
+                    self.stats["cold_starts"] += 1
+                return None
 
-        ledger = TimeLedger()
-        view = self.pool.host_view(self.host, ledger)
-        reader = SnapshotReader(borrow.regions, view, self.pool.rdma)
-        # §3.3: after a successful borrow, invalidate potentially-stale lines
-        reader.invalidate_cxl()
-        manifest, _meta = reader.machine_state()
+            ledger = TimeLedger()
+            view = self.pool.host_view(self.host, ledger)
+            reader = SnapshotReader(borrow.regions, view, self.pool.rdma)
+            # §3.3: after a successful borrow, invalidate potentially-stale lines
+            reader.invalidate_cxl()
+            manifest, _meta = reader.machine_state()
 
-        instance = Instance(StateImage.empty_like(manifest), ledger,
-                            clock=self.pool.clock)
-        if self.use_node_server:
-            engine = self._get_server().attach(
-                name, borrow.regions.version, reader, instance,
-                scatter_fn=self.scatter_fn)
-        else:
-            rdma_engine = (
-                AsyncRDMAEngine(self.pool.rdma, ledger, host=self.host)
-                if self.use_async_rdma else None
-            )
-            engine = RestoreEngine(
-                reader, instance, rdma_engine, BufferPool(self.buffer_pool_pages),
-                scatter_fn=self.scatter_fn,
-            )
-            if self.heat is not None:
-                hm = self.heat.map_for(name, borrow.regions.version,
-                                       instance.image.total_pages)
-                hm.note_restore()
-                engine.heat = hm
-            # A/B honesty: a private-engine restore is still one stream on
-            # the host's CXL link and RNIC — register it so its modeled
-            # time sees the same contention the shared runtime sees
-            key = ("restore", id(engine))
-            for tier in (self.pool.cxl, self.pool.rdma):
-                arbiter = tier.arbiter_for(self.host)
-                arbiter.register(key)
-                engine.link_keys.append((arbiter, key))
+            instance = Instance(StateImage.empty_like(manifest), ledger,
+                                clock=self.pool.clock)
+            if self.use_node_server:
+                engine = self._get_server().attach(
+                    name, borrow.regions.version, reader, instance,
+                    scatter_fn=self.scatter_fn)
+            else:
+                rdma_engine = (
+                    AsyncRDMAEngine(self.pool.rdma, ledger, host=self.host)
+                    if self.use_async_rdma else None
+                )
+                engine = RestoreEngine(
+                    reader, instance, rdma_engine, BufferPool(self.buffer_pool_pages),
+                    scatter_fn=self.scatter_fn,
+                )
+                if self.heat is not None:
+                    hm = self.heat.map_for(name, borrow.regions.version,
+                                           instance.image.total_pages)
+                    hm.note_restore()
+                    engine.heat = hm
+                # A/B honesty: a private-engine restore is still one stream on
+                # the host's CXL link and RNIC — register it so its modeled
+                # time sees the same contention the shared runtime sees
+                key = ("restore", id(engine))
+                for tier in (self.pool.cxl, self.pool.rdma):
+                    arbiter = tier.arbiter_for(self.host)
+                    arbiter.register(key)
+                    engine.link_keys.append((arbiter, key))
         try:
             if pre_install:
                 engine.pre_install_hot()

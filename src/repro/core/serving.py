@@ -55,6 +55,16 @@ from .pool import (
     uffd_zeropage_range_cost,
 )
 from .snapshot import SnapshotReader
+from ..spans import (
+    RESTORE_COLD,
+    RESTORE_CXL_READ,
+    RESTORE_HOT,
+    RESTORE_INSTALL,
+    RESTORE_RDMA_READ,
+    RESTORE_ZERO,
+    span,
+    spanned,
+)
 
 # scatter_fn(dest_matrix, compact, indices) -> dest_matrix; the numpy oracle
 # is a vectorized fancy-index store, the Pallas `page_scatter` op plugs in
@@ -129,6 +139,7 @@ class Instance:
             self._cv.notify_all()
             return True
 
+    @spanned(RESTORE_INSTALL)
     def uffd_copy_batch(self, pages: np.ndarray, mat: np.ndarray) -> int:
         """Install many pages under ONE lock acquisition via a vectorized
         scatter; the ledger is charged per contiguous range (one uffd.copy
@@ -471,6 +482,7 @@ class RestoreEngine:
     # -- phase 1: hot-set pre-installation (§3.4) ------------------------------
     HOT_CHUNK_PAGES = 256   # 1 MiB sequential CXL reads over the compact region
 
+    @spanned(RESTORE_HOT)
     def pre_install_hot(self, use_batch: bool = True,
                         chunk_pages: Optional[int] = None) -> int:
         """uffd.copy the hot set from CXL before resume. Serialized (§5.2).
@@ -517,18 +529,19 @@ class RestoreEngine:
                 n_hot += int(pages.size)
                 continue    # already installed (e.g. repeated pre-install)
             try:
-                if self.server is not None:
-                    # hot-chunk fan-out: co-located same-snapshot restores
-                    # share one physical chunk read (one CXL read, k
-                    # scatters); dedup chunks are content-keyed, so
-                    # different VARIANTS share too
-                    raw = self.server.hot_chunk(self, pool_off, nbytes)
-                else:
-                    raw = call_with_retries(
-                        lambda o=pool_off, n=nbytes: self.reader.view.read(o, n),
-                        policy=self.retry, rng=self._retry_rng,
-                        ledger=self.ledger, clock=self.clock,
-                        trace=self.retry_trace)
+                with span(RESTORE_CXL_READ):
+                    if self.server is not None:
+                        # hot-chunk fan-out: co-located same-snapshot restores
+                        # share one physical chunk read (one CXL read, k
+                        # scatters); dedup chunks are content-keyed, so
+                        # different VARIANTS share too
+                        raw = self.server.hot_chunk(self, pool_off, nbytes)
+                    else:
+                        raw = call_with_retries(
+                            lambda o=pool_off, n=nbytes: self.reader.view.read(o, n),
+                            policy=self.retry, rng=self._retry_rng,
+                            ledger=self.ledger, clock=self.clock,
+                            trace=self.retry_trace)
             except TierFaultError as e:
                 if ht is None:
                     raise
@@ -1000,20 +1013,26 @@ class RestoreEngine:
                         self.ledger.add("rdma_read", self._rdma_arbiter.charge(nbytes))
                         self.instance.uffd_copy(page, self.reader.read_page(page))
             return
-        for start, n in self.reader.zero_runs():
-            self.instance.uffd_zeropage_range(int(start), int(n))
+        with span(RESTORE_ZERO):
+            for start, n in self.reader.zero_runs():
+                self.instance.uffd_zeropage_range(int(start), int(n))
         self.pre_install_hot()
         self.drain_degraded_hot()
+        with span(RESTORE_COLD):
+            self._install_cold_runs()
+
+    def _install_cold_runs(self) -> None:
         if self.reader.regions.dedup:
             # dedup cold pages are not rank-compacted: walk the dual-
             # contiguous extents (split only at store discontinuities)
             for es, en, _rank0, pool_off, nbytes in self.reader.iter_cold_extents(
                     max_extent_pages=1 << 30):
-                payload = call_with_retries(
-                    lambda o=pool_off, b=nbytes: self.reader.rdma.read(o, b),
-                    policy=self.retry, rng=self._retry_rng,
-                    ledger=self.ledger, clock=self.clock,
-                    trace=self.retry_trace)
+                with span(RESTORE_RDMA_READ):
+                    payload = call_with_retries(
+                        lambda o=pool_off, b=nbytes: self.reader.rdma.read(o, b),
+                        policy=self.retry, rng=self._retry_rng,
+                        ledger=self.ledger, clock=self.clock,
+                        trace=self.retry_trace)
                 self.ledger.add("rdma_read", self._rdma_arbiter.charge(nbytes))
                 self._install_verified(np.arange(es, es + en),
                                        payload.reshape(en, PAGE_SIZE))
@@ -1022,11 +1041,12 @@ class RestoreEngine:
             start, n = int(start), int(n)
             rank0 = self.reader.cold_rank(start)
             pool_off, nbytes = self.reader.cold_extent_span(rank0, n)
-            payload = call_with_retries(
-                lambda o=pool_off, b=nbytes: self.reader.rdma.read(o, b),
-                policy=self.retry, rng=self._retry_rng,
-                ledger=self.ledger, clock=self.clock,
-                trace=self.retry_trace)
+            with span(RESTORE_RDMA_READ):
+                payload = call_with_retries(
+                    lambda o=pool_off, b=nbytes: self.reader.rdma.read(o, b),
+                    policy=self.retry, rng=self._retry_rng,
+                    ledger=self.ledger, clock=self.clock,
+                    trace=self.retry_trace)
             self.ledger.add("rdma_read", self._rdma_arbiter.charge(nbytes))
             self._install_verified(np.arange(start, start + n),
                                    self.reader.split_cold_extent(rank0, n, payload))

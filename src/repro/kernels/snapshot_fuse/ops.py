@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...spans import SCATTER_LAUNCH, SCATTER_STAGE, SCATTER_VERIFY, span
 from ..backend import on_tpu
 from ..layout import LANES, page_tiles, page_words, weight_tile
 from .kernel import fused_publish_pallas, fused_restore_pallas
@@ -179,31 +180,38 @@ def fused_restore(dest, compact: np.ndarray, indices: np.ndarray,
         src_indices = np.asarray(src_indices, dtype=np.int32)
     if m == 0:
         return dest, np.zeros(0, np.uint32)
-    chunk = page_tiles(compact)
     if not use_pallas:
+        chunk = page_tiles(compact)
         out = dest if isinstance(dest, np.ndarray) else np.asarray(dest).copy()
         _, csums = fused_restore_ref(page_words(out), page_words(chunk),
                                      src_indices, indices)
+        parts = None
     else:
-        out = (jnp.asarray(page_tiles(dest)) if isinstance(dest, np.ndarray)
-               else dest)
-        w = weight_tile(chunk.shape[1])
+        with span(SCATTER_STAGE):
+            chunk = page_tiles(compact)
+            out = (jnp.asarray(page_tiles(dest)) if isinstance(dest, np.ndarray)
+                   else dest)
+            w = weight_tile(chunk.shape[1])
         parts = []
         for lo in range(0, m, MAX_BATCH_PAGES):
-            src = src_indices[lo : lo + MAX_BATCH_PAGES]
-            dst = indices[lo : lo + MAX_BATCH_PAGES]
-            pad = _bucket(dst.size) - dst.size
-            rows = chunk[np.concatenate([src, np.repeat(src[-1:], pad)])]
-            out, c = fused_restore_pallas(
-                out, jnp.asarray(rows), jnp.arange(rows.shape[0], dtype=jnp.int32),
-                jnp.asarray(np.concatenate([dst, np.repeat(dst[-1:], pad)])),
-                w, interpret=interpret)
+            with span(SCATTER_STAGE):
+                src = src_indices[lo : lo + MAX_BATCH_PAGES]
+                dst = indices[lo : lo + MAX_BATCH_PAGES]
+                pad = _bucket(dst.size) - dst.size
+                rows = chunk[np.concatenate([src, np.repeat(src[-1:], pad)])]
+                args = (jnp.asarray(rows), jnp.arange(rows.shape[0], dtype=jnp.int32),
+                        jnp.asarray(np.concatenate([dst, np.repeat(dst[-1:], pad)])))
+            with span(SCATTER_LAUNCH):
+                out, c = fused_restore_pallas(out, *args, w, interpret=interpret)
             parts.append((c, dst.size))
-        csums = np.concatenate([np.asarray(c)[:k] for c, k in parts])
-    if expected_csums is not None:
-        bad = csums != np.asarray(expected_csums, dtype=np.uint32)
-        if bad.any():
-            raise ChecksumMismatchError(indices[bad], dest=out)
+    with span(SCATTER_VERIFY):
+        if parts is not None:
+            # reading the checksums back waits for each batch's kernel
+            csums = np.concatenate([np.asarray(c)[:k] for c, k in parts])
+        if expected_csums is not None:
+            bad = csums != np.asarray(expected_csums, dtype=np.uint32)
+            if bad.any():
+                raise ChecksumMismatchError(indices[bad], dest=out)
     return out, csums
 
 

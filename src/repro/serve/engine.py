@@ -15,6 +15,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..models.model_zoo import Model, build
+from ..spans import SERVE_PREFILL, spanned
 
 
 @dataclasses.dataclass
@@ -27,6 +28,7 @@ class ServerInstance:
     max_len: int
     pos: int = 0
 
+    @spanned(SERVE_PREFILL)
     def prefill(self, tokens: jnp.ndarray) -> jnp.ndarray:
         """Feed prompt tokens (B, S); returns last-position logits (B, V)."""
         logits, self.caches = _prefill_scan(
@@ -60,9 +62,10 @@ _decode_cache: Dict[str, Any] = {}
 def _decode_jit(model: Model):
     key = model.cfg.name
     if key not in _decode_cache:
-        def step(params, tokens, caches, pos):
+        # named so that its program reads ``jit_decode_step`` in a trace
+        def decode_step(params, tokens, caches, pos):
             return model.decode_step(params, {"tokens": tokens, "pos": pos}, caches)
-        _decode_cache[key] = jax.jit(step)
+        _decode_cache[key] = jax.jit(decode_step)
     return _decode_cache[key]
 
 
