@@ -139,9 +139,10 @@ def restore_checkpoint(
     end of the borrow and the hot pre-install (``Orchestrator.restore``);
     ``stats["time_to_full_s"]`` runs on to the end of every install
     (``install_all_sync``).  Neither waits for the device beyond what the
-    installs wait for themselves: on the kernel data plane each batch's
-    checksum readback waits for that batch's kernel, and the arrays extracted
-    afterwards are dispatched, not awaited.  The whole restore is the span
+    installs wait for themselves: on the kernel data plane the hot and the
+    cold phase each read their checksums back once, at their end, which waits
+    for their kernels, and the arrays extracted afterwards are dispatched,
+    not awaited.  The whole restore is the span
     ``aquifer.restore`` (repro/spans.py).
     """
     t0 = time.perf_counter()

@@ -24,6 +24,7 @@ posted at high priority so they overtake queued prefetch extents (§3.4).
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import queue
 import random
@@ -71,7 +72,8 @@ from ..spans import (
 # behind the same signature (kernels/page_scatter), and so does the fused
 # gather→checksum→scatter kernel (kernels/snapshot_fuse.FusedScatter —
 # RestoreEngine binds it to the snapshot's publish-time checksum table so
-# every installed batch is verified inside the installing kernel call).
+# every installed batch's checksum, computed by the kernel that installs it,
+# is compared with the table: per call, or once per bulk install phase).
 ScatterFn = Callable[[np.ndarray, np.ndarray, np.ndarray], np.ndarray]
 
 
@@ -83,13 +85,20 @@ class Instance:
     plane) — ``device_pages``: one donated device page array that every
     install writes into, sending only the compact rows and their indices.
     It starts zeroed, so zero-page installs only mark pages present, as on
-    the host.  ``present`` and the ledger stay on the host either way."""
+    the host.  ``present`` and the ledger stay on the host either way.
+
+    A bulk install (:meth:`defer_checks` … :meth:`settle_checks`) holds its
+    pages in ``pending``, written but with their checksum compare still
+    held, and moves them to ``present`` only once they verified: no reader
+    of ``present`` (``wait_present``, ``RestoreEngine.access``) sees an
+    unverified page (DESIGN.md §13)."""
 
     def __init__(self, image: StateImage, ledger: Optional[TimeLedger] = None,
                  scatter_fn: Optional[ScatterFn] = None,
                  clock: Optional[Clock] = None):
         self.image = image
         self.present = np.zeros(image.total_pages, dtype=bool)
+        self.pending = np.zeros(image.total_pages, dtype=bool)
         self.ledger = ledger or TimeLedger()
         self.device_pages = None
         self.set_scatter(scatter_fn)
@@ -106,6 +115,8 @@ class Instance:
         }
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
+        self._checks = None          # the open bulk install's PendingChecks
+        self._checks_thread: Optional[int] = None
 
     def set_scatter(self, scatter_fn: Optional[ScatterFn]) -> None:
         """Route installs through ``scatter_fn``; a scatter that allocates
@@ -124,12 +135,46 @@ class Instance:
             return self.image.buf
         return np.asarray(self.device_pages).view(np.uint8).reshape(-1)
 
+    # -- bulk installs: one checksum readback each (DESIGN.md §13) -----------
+    def defer_checks(self) -> bool:
+        """Open a bulk install on the calling thread: until
+        :meth:`settle_checks`, its batch installs hold their checksum
+        compares and leave their pages ``pending``.  Installs from other
+        threads verify per call.  False when there is nothing to defer: the
+        scatter verifies nothing, or a bulk install is already open."""
+        make = getattr(self.scatter_fn, "pending_checks", None)
+        checks = make() if make is not None else None
+        with self._cv:
+            if checks is None or self._checks is not None:
+                return False
+            self._checks, self._checks_thread = checks, threading.get_ident()
+        return True
+
+    def settle_checks(self, discard: bool = False) -> np.ndarray:
+        """Close the bulk install: ONE checksum readback and compare for all
+        of it.  Pages that verified become present; those that did not are
+        returned, absent, for the repair path.  ``discard`` (the install
+        failed) compares nothing and leaves every held page absent."""
+        with self._cv:
+            checks, self._checks, self._checks_thread = self._checks, None, None
+        bad = np.zeros(0, np.int64) if discard else checks.settle()
+        with self._cv:
+            if discard:
+                bad = np.flatnonzero(self.pending)
+            self.pending[bad] = False
+            self.present |= self.pending
+            self.pending[:] = False
+            self.stats["uffd_copies"] -= int(bad.size)
+            self.stats["bytes_installed"] -= int(bad.size) * PAGE_SIZE
+            self._cv.notify_all()
+        return bad
+
     # -- uffd analogues ------------------------------------------------------
     def uffd_copy(self, page: int, src: np.ndarray) -> bool:
         if self.device_pages is not None:
             return self.uffd_copy_batch(np.array([page]), src) == 1
         with self._cv:
-            if self.present[page]:
+            if self.present[page] or self.pending[page]:
                 return False
             self.image.write_page(page, src)
             self.present[page] = True
@@ -143,18 +188,24 @@ class Instance:
     def uffd_copy_batch(self, pages: np.ndarray, mat: np.ndarray) -> int:
         """Install many pages under ONE lock acquisition via a vectorized
         scatter; the ledger is charged per contiguous range (one uffd.copy
-        ioctl per range), not per page.  Already-present pages are skipped.
-        Returns the number of pages actually installed."""
+        ioctl per range), not per page.  Already-present (or pending) pages are
+        skipped.  Inside this thread's bulk install the pages go to
+        ``pending``, their checksum compare held until it settles.  Returns the
+        number of pages actually installed."""
         pages = np.asarray(pages, dtype=np.int64)
         mat = np.ascontiguousarray(mat).view(np.uint8).reshape(pages.size, PAGE_SIZE)
         with self._cv:
-            todo = ~self.present[pages]
+            todo = ~(self.present[pages] | self.pending[pages])
             if not todo.any():
                 return 0
             sel = pages[todo]
+            held = (self._checks if self._checks_thread == threading.get_ident()
+                    else None)
+            scatter = (self.scatter_fn if held is None
+                       else functools.partial(self.scatter_fn, checks=held))
             if self.device_pages is not None:
                 try:
-                    self.device_pages = self.scatter_fn(
+                    self.device_pages = scatter(
                         self.device_pages, mat[todo], sel)
                 except RuntimeError as err:
                     # a failed verify still installed into (and donated)
@@ -165,12 +216,12 @@ class Instance:
             else:
                 pm = self.image.pages_matrix()
                 if self.scatter_fn is not None:
-                    out = np.asarray(self.scatter_fn(pm, mat[todo], sel))
+                    out = np.asarray(scatter(pm, mat[todo], sel))
                     if out is not pm:      # functional (jax) scatter returned a copy
                         pm[sel] = out[sel]
                 else:
                     pm[sel] = mat[todo]
-            self.present[sel] = True
+            (self.present if held is None else self.pending)[sel] = True
             n = int(sel.size)
             n_ranges = int(1 + np.count_nonzero(np.diff(sel) != 1))
             self.stats["uffd_copies"] += n
@@ -397,6 +448,28 @@ class AsyncRDMAEngine:
             self._worker.join(timeout=1.0)
 
 
+def _bulk_install(phase: Callable) -> Callable:
+    """A bulk install phase of :class:`RestoreEngine`: the checksums of every
+    batch it installs are read back and compared once, when it ends, and the
+    pages that fail go through the repair path then.  Installs outside a bulk
+    phase (demand faults, prefetch and node-server completions, repairs)
+    verify per call (DESIGN.md §13)."""
+    @functools.wraps(phase)
+    def run(self, *args, **kwargs):
+        if not self.instance.defer_checks():
+            return phase(self, *args, **kwargs)
+        try:
+            result = phase(self, *args, **kwargs)
+        except BaseException:
+            self.instance.settle_checks(discard=True)
+            raise
+        bad = self.instance.settle_checks()
+        if bad.size:
+            self._repair_pages(bad)
+        return result
+    return run
+
+
 class RestoreEngine:
     """Per-instance page server: run-coalesced hot pre-install + async cold
     demand-paging + optional background extent prefetch over the cold runs."""
@@ -422,7 +495,8 @@ class RestoreEngine:
             # the scatter that installs each batch also verifies it —
             # covers pre_install_hot, install_all_sync, demand/prefetch
             # installs AND the NodePageServer hot-chunk fan-out path, all of
-            # which land in Instance.uffd_copy_batch
+            # which land in Instance.uffd_copy_batch (the bulk phases compare
+            # once, at their end: _bulk_install)
             table = (reader.page_checksums()
                      if hasattr(scatter_fn, "bind_checksums") else None)
             if table is not None:
@@ -483,6 +557,7 @@ class RestoreEngine:
     HOT_CHUNK_PAGES = 256   # 1 MiB sequential CXL reads over the compact region
 
     @spanned(RESTORE_HOT)
+    @_bulk_install
     def pre_install_hot(self, use_batch: bool = True,
                         chunk_pages: Optional[int] = None) -> int:
         """uffd.copy the hot set from CXL before resume. Serialized (§5.2).
@@ -499,7 +574,8 @@ class RestoreEngine:
         With a fused scatter_fn (kernels/snapshot_fuse) each chunk install
         is one gather→checksum→scatter kernel whose input stream pipelines
         against the previous chunk's scatter (double-buffered grid), and is
-        verified against publish-time checksums when the reader carries them.
+        verified against publish-time checksums when the reader carries them:
+        once for the whole walk, when it ends (``_bulk_install``).
         """
         if not use_batch:
             hot = self.reader.hot_page_indices()
@@ -607,15 +683,17 @@ class RestoreEngine:
         mat = np.ascontiguousarray(mat).view(np.uint8).reshape(
             pages.size, PAGE_SIZE)
         bad = {int(p) for p in np.atleast_1d(np.asarray(bad_pages))}
-        self.repair_stats["checksum_mismatches"] += len(bad)
         good = np.array([i for i, p in enumerate(pages) if int(p) not in bad],
                         dtype=np.int64)
         n = 0
         if good.size:
             n += self.instance.uffd_copy_batch(pages[good], mat[good])
-        for p in sorted(bad):
-            n += self._repair_page(int(p))
-        return n
+        return n + self._repair_pages(sorted(bad))
+
+    def _repair_pages(self, bad) -> int:
+        """Repair each checksum-bad guest page under :attr:`repair_budget`."""
+        self.repair_stats["checksum_mismatches"] += len(bad)
+        return sum(self._repair_page(int(p)) for p in bad)
 
     def _reread_home(self, page: int, kind: str, off: int) -> np.ndarray:
         """Budgeted re-read from the page's home tier, charged like a fresh
@@ -1021,6 +1099,7 @@ class RestoreEngine:
         with span(RESTORE_COLD):
             self._install_cold_runs()
 
+    @_bulk_install
     def _install_cold_runs(self) -> None:
         if self.reader.regions.dedup:
             # dedup cold pages are not rank-compacted: walk the dual-

@@ -8,8 +8,10 @@
 ``FusedScatter``    — ``fused_restore`` adapted to the serving layer's
                       ``ScatterFn`` signature ``(dest, compact, indices) ->
                       dest``; optionally bound to a snapshot's publish-time
-                      checksum table, in which case every installed page is
-                      verified in the same kernel invocation that installs it.
+                      checksum table, in which case every installed page's
+                      checksum, computed by the kernel that installs it, is
+                      compared with the table: per call, or once at the end
+                      of a bulk install (``PendingChecks``).
 
 The backend is explicit and never falls back.  ``use_pallas=False`` runs the
 numpy oracle on host page matrices (the restore updates them in place);
@@ -24,11 +26,13 @@ compiled kernels on a TPU, the host path elsewhere.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ...spans import SCATTER_LAUNCH, SCATTER_STAGE, SCATTER_VERIFY, span
 from ..backend import on_tpu
@@ -157,6 +161,57 @@ def _bucket(m: int) -> int:
     return 1 << max(0, int(m) - 1).bit_length()
 
 
+def _install(dest, compact: np.ndarray, indices: np.ndarray,
+             src_indices: np.ndarray, use_pallas: bool, interpret: bool,
+             checks: Optional["PendingChecks"] = None):
+    """Install ``compact[src_indices[i]]`` at ``dest[indices[i]]`` without
+    waiting for it.  Returns ``(dest', got)``: ``got`` is the host checksum
+    vector (host path), or per kernel batch ``(csums, lo, k)``, its padded
+    device checksum vector and the ``indices[lo : lo + k]`` it installed.
+    Given ``checks``, each batch's checksums also go to its device stash."""
+    if indices.shape[0] == 0:
+        return dest, np.zeros(0, np.uint32)
+    if not use_pallas:
+        chunk = page_tiles(compact)
+        out = dest if isinstance(dest, np.ndarray) else np.asarray(dest).copy()
+        _, csums = fused_restore_ref(page_words(out), page_words(chunk),
+                                     src_indices, indices)
+        return out, csums
+    with span(SCATTER_STAGE):
+        chunk = page_tiles(compact)
+        out = (jnp.asarray(page_tiles(dest)) if isinstance(dest, np.ndarray)
+               else dest)
+        w = weight_tile(chunk.shape[1])
+    parts = []
+    for lo in range(0, indices.shape[0], MAX_BATCH_PAGES):
+        with span(SCATTER_STAGE):
+            src = src_indices[lo : lo + MAX_BATCH_PAGES]
+            dst = indices[lo : lo + MAX_BATCH_PAGES]
+            pad = _bucket(dst.size) - dst.size
+            rows = chunk[np.concatenate([src, np.repeat(src[-1:], pad)])]
+            args = (jnp.asarray(rows), jnp.arange(rows.shape[0], dtype=jnp.int32),
+                    jnp.asarray(np.concatenate([dst, np.repeat(dst[-1:], pad)])))
+        with span(SCATTER_LAUNCH):
+            out, c = fused_restore_pallas(out, *args, w, interpret=interpret)
+            if checks is not None:
+                checks.stash(c)
+        parts.append((c, lo, dst.size))
+    return out, parts
+
+
+def _read_back(got) -> np.ndarray:
+    """Host checksums of an :func:`_install`: waits for its kernel batches."""
+    if isinstance(got, np.ndarray):
+        return got
+    return np.concatenate([np.asarray(c)[:k] for c, _lo, k in got])
+
+
+def _check(csums: np.ndarray, indices: np.ndarray, expected, dest) -> None:
+    bad = csums != np.asarray(expected, dtype=np.uint32)
+    if bad.any():
+        raise ChecksumMismatchError(indices[bad], dest=dest)
+
+
 def fused_restore(dest, compact: np.ndarray, indices: np.ndarray,
                   *, src_indices: Optional[np.ndarray] = None,
                   expected_csums: Optional[np.ndarray] = None,
@@ -171,52 +226,131 @@ def fused_restore(dest, compact: np.ndarray, indices: np.ndarray,
     array returned.  Only the compact rows and their indices cross to the
     device, in batches of at most :data:`MAX_BATCH_PAGES`, each padded to a
     power of two by repeating its last (source, destination) pair, which
-    rewrites the same page."""
+    rewrites the same page.  The checksums are read back once, after the
+    last batch."""
     indices = np.asarray(indices, dtype=np.int32)
-    m = indices.shape[0]
     if src_indices is None:
-        src_indices = np.arange(m, dtype=np.int32)
+        src_indices = np.arange(indices.shape[0], dtype=np.int32)
     else:
         src_indices = np.asarray(src_indices, dtype=np.int32)
-    if m == 0:
-        return dest, np.zeros(0, np.uint32)
-    if not use_pallas:
-        chunk = page_tiles(compact)
-        out = dest if isinstance(dest, np.ndarray) else np.asarray(dest).copy()
-        _, csums = fused_restore_ref(page_words(out), page_words(chunk),
-                                     src_indices, indices)
-        parts = None
-    else:
-        with span(SCATTER_STAGE):
-            chunk = page_tiles(compact)
-            out = (jnp.asarray(page_tiles(dest)) if isinstance(dest, np.ndarray)
-                   else dest)
-            w = weight_tile(chunk.shape[1])
-        parts = []
-        for lo in range(0, m, MAX_BATCH_PAGES):
-            with span(SCATTER_STAGE):
-                src = src_indices[lo : lo + MAX_BATCH_PAGES]
-                dst = indices[lo : lo + MAX_BATCH_PAGES]
-                pad = _bucket(dst.size) - dst.size
-                rows = chunk[np.concatenate([src, np.repeat(src[-1:], pad)])]
-                args = (jnp.asarray(rows), jnp.arange(rows.shape[0], dtype=jnp.int32),
-                        jnp.asarray(np.concatenate([dst, np.repeat(dst[-1:], pad)])))
-            with span(SCATTER_LAUNCH):
-                out, c = fused_restore_pallas(out, *args, w, interpret=interpret)
-            parts.append((c, dst.size))
+    out, got = _install(dest, compact, indices, src_indices, use_pallas, interpret)
     with span(SCATTER_VERIFY):
-        if parts is not None:
-            # reading the checksums back waits for each batch's kernel
-            csums = np.concatenate([np.asarray(c)[:k] for c, k in parts])
+        csums = _read_back(got)
         if expected_csums is not None:
-            bad = csums != np.asarray(expected_csums, dtype=np.uint32)
-            if bad.any():
-                raise ChecksumMismatchError(indices[bad], dest=out)
+            _check(csums, indices, expected_csums, out)
     return out, csums
 
 
+# A bulk install's device checksums are written into a stash vector of this
+# many entries (4 MiB), a new one when it fills: the hot phase of a
+# 1M-page restore holds one vector, not a buffer per kernel batch.
+STASH_ENTRIES = 1 << 20
+# Batches of one shape are written to the stash this many at a time: a
+# dispatch costs about as much host time as the kernel's own, so one per
+# batch would give back a quarter of what holding the compares saves.
+STASH_GROUP = 32
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _stash(seg: jax.Array, pos: jax.Array, *csums: jax.Array):
+    """Write the batches' checksums, in order, at ``pos`` in ``seg``;
+    advance ``pos``."""
+    c = jnp.concatenate(csums)
+    return lax.dynamic_update_slice(seg, c, (pos,)), pos + c.shape[0]
+
+
+def _pad_to(a: np.ndarray, n: int) -> np.ndarray:
+    """``a`` with its last element repeated up to ``n`` entries, as the
+    kernel pads a batch."""
+    if a.size == n:
+        return a
+    return np.concatenate([a, np.repeat(a[-1:], n - a.size)])
+
+
+class PendingChecks:
+    """The checksum compares of one bulk install, held until it ends.
+
+    Each held batch keeps its guest indices and expected checksums on the
+    host.  Its computed checksums stay where they were made: host arrays on
+    the host path; on the kernel path the padded batch vectors are written,
+    :data:`STASH_GROUP` of one shape at a time, into a device stash of
+    :data:`STASH_ENTRIES` entries, so a phase of thousands of batches keeps
+    a few device vectors alive, not one per batch.  :meth:`settle` reads
+    them back once and compares everything in one vectorised step;
+    ``stats["verify_syncs"]`` counts that wait."""
+
+    def __init__(self, stats: dict):
+        self.stats = stats
+        self._dst: list = []
+        self._exp: list = []
+        self._host: list = []
+        self._full: list = []        # filled stash vectors: (array, used)
+        self._seg = self._pos = None
+        self._used = 0
+        self._group: list = []       # batch vectors of one shape, not yet written
+
+    def stash(self, csums: jax.Array) -> None:
+        """Queue one kernel batch's padded checksums for the device stash."""
+        if self._group and csums.shape != self._group[0].shape:
+            self._flush()
+        self._group.append(csums)
+        if len(self._group) == STASH_GROUP:
+            self._write(self._group)
+            self._group = []
+
+    def _flush(self) -> None:
+        """Write a part-filled group one batch at a time (one program per
+        batch shape, not one per group size)."""
+        for c in self._group:
+            self._write([c])
+        self._group = []
+
+    def _write(self, csums: list) -> None:
+        """One dispatch: ``csums`` into the stash, in a new stash vector when
+        this one has no room left."""
+        n = sum(c.shape[0] for c in csums)
+        if self._seg is None or self._used + n > STASH_ENTRIES:
+            if self._seg is not None:
+                self._seg.copy_to_host_async()
+                self._full.append((self._seg, self._used))
+            self._seg = jnp.zeros(STASH_ENTRIES, jnp.uint32)
+            self._pos = jnp.zeros((), jnp.int32)
+            self._used = 0
+        self._seg, self._pos = _stash(self._seg, self._pos, *csums)
+        self._used += n
+
+    def hold(self, got, indices: np.ndarray, expected: np.ndarray) -> None:
+        """Keep one install's guest indices and expected checksums, aligned
+        with its checksums ``got`` (from :func:`_install`)."""
+        if isinstance(got, np.ndarray):
+            self._host.append(got)
+            self._dst.append(indices)
+            self._exp.append(expected)
+            return
+        for c, lo, k in got:
+            self._dst.append(_pad_to(indices[lo : lo + k], c.shape[0]))
+            self._exp.append(_pad_to(expected[lo : lo + k], c.shape[0]))
+
+    def settle(self) -> np.ndarray:
+        """ONE readback and ONE compare for everything held: the sorted guest
+        pages whose checksum disagreed."""
+        if not self._dst:
+            return np.zeros(0, np.int64)
+        self.stats["verify_syncs"] += 1
+        with span(SCATTER_VERIFY):
+            self._flush()
+            if self._seg is None:
+                got = np.concatenate(self._host)
+            else:
+                stash = self._full + [(self._seg, self._used)]
+                host = jax.device_get([a for a, _used in stash])
+                got = np.concatenate([h[:used] for h, (_a, used) in zip(host, stash)])
+            bad = got != np.concatenate(self._exp)
+            return np.unique(np.concatenate(self._dst)[bad]).astype(np.int64)
+
+
 class FusedScatter:
-    """``ScatterFn``-shaped adapter over :func:`fused_restore`.
+    """``ScatterFn``-shaped adapter over the fused restore.
 
     Drop-in for the serving layer's scatter seam (``Instance``,
     ``RestoreEngine``, ``NodePageServer.attach``, ``Orchestrator``): the
@@ -224,10 +358,14 @@ class FusedScatter:
     to a snapshot's guest-indexed publish-time checksum table
     (:meth:`bind_checksums` — ``RestoreEngine.__init__`` does this when the
     reader's regions carry one), every batch is verified against
-    ``table[indices]`` inside the same fused invocation that installs it.
-    Bound copies share the template's ``stats`` dict so fan-out totals stay
-    observable in one place.  With ``use_pallas`` the instance's memory is
-    the device array :meth:`new_memory` allocates.
+    ``table[indices]``: at once, reading its checksums back, or — given the
+    ``checks`` of a bulk install (:meth:`pending_checks`) — when that
+    install ends (DESIGN.md §13).  Unbound, it reads each call's checksums
+    back and compares nothing.  ``stats["verify_syncs"]`` counts the host's
+    waits on checksum readbacks.  Bound copies share the template's
+    ``stats`` dict so fan-out totals stay observable in one place.  With
+    ``use_pallas`` the instance's memory is the device array
+    :meth:`new_memory` allocates.
     """
 
     def __init__(self, *, use_pallas: bool = False, interpret: bool = False,
@@ -237,7 +375,7 @@ class FusedScatter:
         self.interpret = interpret
         self.expected = None if expected is None else np.asarray(expected, np.uint32)
         self.stats = stats if stats is not None else {
-            "batches": 0, "pages": 0, "pages_verified": 0}
+            "batches": 0, "pages": 0, "pages_verified": 0, "verify_syncs": 0}
 
     def bind_checksums(self, table: np.ndarray) -> "FusedScatter":
         return FusedScatter(use_pallas=self.use_pallas, interpret=self.interpret,
@@ -251,16 +389,29 @@ class FusedScatter:
         return jnp.zeros((total_pages, page_bytes // (4 * LANES), LANES),
                          jnp.uint32)
 
-    def __call__(self, dest, compact: np.ndarray, indices: np.ndarray):
-        idx = np.asarray(indices)
+    def pending_checks(self) -> Optional[PendingChecks]:
+        """Holder for a bulk install's deferred compares, or None when this
+        scatter verifies nothing."""
+        return None if self.expected is None else PendingChecks(self.stats)
+
+    def __call__(self, dest, compact: np.ndarray, indices: np.ndarray,
+                 checks: Optional[PendingChecks] = None):
+        idx = np.asarray(indices, dtype=np.int32)
         exp = self.expected[idx] if self.expected is not None else None
-        out, _csums = fused_restore(dest, compact, idx, expected_csums=exp,
-                                    use_pallas=self.use_pallas,
-                                    interpret=self.interpret)
-        self.stats["batches"] += 1
-        self.stats["pages"] += int(idx.size)
+        if checks is None:
+            self.stats["verify_syncs"] += 1
+            out, _csums = fused_restore(dest, compact, idx, expected_csums=exp,
+                                        use_pallas=self.use_pallas,
+                                        interpret=self.interpret)
+        else:
+            out, got = _install(dest, compact, idx,
+                                np.arange(idx.size, dtype=np.int32),
+                                self.use_pallas, self.interpret, checks)
+            checks.hold(got, idx, exp)
         if exp is not None:
             self.stats["pages_verified"] += int(idx.size)
+        self.stats["batches"] += 1
+        self.stats["pages"] += int(idx.size)
         return out
 
 

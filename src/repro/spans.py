@@ -31,8 +31,8 @@ RESTORE_RDMA_READ = PREFIX + "restore.rdma_read"  # one cold run's RDMA read
 RESTORE_INSTALL = PREFIX + "restore.install"      # Instance.uffd_copy_batch
 RESTORE_EXTRACT = PREFIX + "restore.extract"      # named arrays cut from the page array
 SCATTER_STAGE = PREFIX + "scatter.stage"          # fused_restore: rows gathered, uploaded
-SCATTER_LAUNCH = PREFIX + "scatter.launch"        # fused_restore: the kernel's dispatch
-SCATTER_VERIFY = PREFIX + "scatter.verify"        # fused_restore: checksum readback, compare
+SCATTER_LAUNCH = PREFIX + "scatter.launch"        # kernel dispatch (+ checksum stash, bulk phases)
+SCATTER_VERIFY = PREFIX + "scatter.verify"        # checksum readback, compare: per call or bulk phase
 SERVE_PREFILL = PREFIX + "serve.prefill"          # ServerInstance.prefill
 
 try:

@@ -453,6 +453,30 @@ class TestChecksumRepair:
         # the repair re-read is charged like a fresh demand read
         assert inst.ledger.seconds.get("cxl_read", 0.0) > 0.0
 
+    @pytest.mark.parametrize("memory", ["host", "device"])
+    def test_demand_fault_verifies_in_its_own_call(self, memory):
+        """A hot page demand-faulted outside any bulk install is compared in
+        the call that installs it: a poisoned read is caught and repaired
+        before ``access`` returns, with one readback for the install and
+        one for the repair."""
+        img, pool, borrow = publish_stack(fused=True)
+        reader = SnapshotReader(borrow.regions, pool.host_view("h"), pool.rdma)
+        hot0 = int(reader.hot_page_indices()[0])
+        _kind, off = reader.lookup(hot0)
+        pool.attach_fault_injector(FaultInjector(seed=4).poison_reads(
+            "cxl", 1, lo=off, hi=off + PAGE_SIZE))
+        scatter = FusedScatter(use_pallas=memory == "device",
+                               interpret=memory == "device")
+        inst = Instance(StateImage.empty_like(img.manifest))
+        engine = RestoreEngine(reader, inst, None, scatter_fn=scatter)
+        engine.access(hot0)
+        assert inst.present[hot0] and not inst.pending.any()
+        assert engine.repair_stats["checksum_mismatches"] == 1
+        assert engine.repair_stats["checksum_repairs"] == 1
+        assert scatter.stats["verify_syncs"] == 2
+        got = inst.image_bytes().reshape(-1, PAGE_SIZE)[hot0]
+        np.testing.assert_array_equal(got, img.pages_matrix()[hot0])
+
     def test_at_rest_corruption_exhausts_repair_budget_and_surfaces(self):
         img, pool, borrow = publish_stack(fused=True)
         probe = SnapshotReader(borrow.regions, pool.host_view("probe"),

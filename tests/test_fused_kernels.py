@@ -8,11 +8,13 @@ publish→restore checksum-verification loop end-to-end.
 """
 import dataclasses
 import functools
+import sys
+import threading
 
 import numpy as np
 import pytest
 
-from repro.core import HierarchicalPool
+from repro.core import FaultInjector, HierarchicalPool
 from repro.core.coherence import Catalog
 from repro.core.dedup import pallas_hash_fn
 from repro.core.master import PoolMaster
@@ -202,6 +204,38 @@ class TestFusedRestore:
         assert ei.value.pages.tolist() == [2]
 
 
+    @pytest.mark.parametrize("bad_page", [None, 13])
+    def test_held_checks_span_stash_vectors(self, bad_page, monkeypatch):
+        """A bulk install's device checksums fill several stash vectors (cut
+        to 16 entries here), written in groups of two batches of one shape
+        and singly otherwise; one settle compares them all and names the one
+        bad guest page, after every batch was installed."""
+        monkeypatch.setattr(snapshot_fuse_ops, "STASH_ENTRIES", 16)
+        monkeypatch.setattr(snapshot_fuse_ops, "STASH_GROUP", 2)
+        rng = np.random.default_rng(12)
+        chunk = rng.integers(0, 256, size=(30, PAGE_SIZE), dtype=np.uint8)
+        table = np.zeros(64, np.uint32)
+        dst = np.sort(rng.choice(64, size=30, replace=False)).astype(np.int32)
+        table[dst] = np.asarray(pallas_hash_fn(chunk))
+        if bad_page is not None:
+            table[dst[bad_page]] ^= 1
+        sf = FusedScatter(**INTERP).bind_checksums(table)
+        checks = sf.pending_checks()
+        dest = sf.new_memory(64, PAGE_SIZE)
+        for lo, hi in [(0, 5), (5, 6), (6, 14), (14, 22), (22, 30)]:
+            dest = sf(dest, chunk[lo:hi], dst[lo:hi], checks=checks)
+        assert sf.stats["verify_syncs"] == 0
+        # padded 8, 1, 8, 8, 8: [8] and [1] singly, [8 8] as a group, and
+        # the last [8] at settle, in a third vector
+        assert len(checks._full) == 1 and checks._used == 16
+        bad = checks.settle()
+        assert len(checks._full) == 2 and checks._used == 8
+        assert bad.tolist() == ([] if bad_page is None else [dst[bad_page]])
+        assert sf.stats["verify_syncs"] == 1
+        got = np.asarray(dest).reshape(64, -1).view(np.uint8)
+        np.testing.assert_array_equal(got[dst], chunk)
+
+
 class TestFusedScatterSeam:
     def test_scatterfn_signature_unbound(self):
         """Drop-in for the serving seam: (dest, compact, indices) -> dest,
@@ -212,7 +246,8 @@ class TestFusedScatterSeam:
         sf = FusedScatter(use_pallas=False)
         out = sf(dest, chunk, idx)
         np.testing.assert_array_equal(out[idx], chunk)
-        assert sf.stats == {"batches": 1, "pages": 4, "pages_verified": 0}
+        assert sf.stats == {"batches": 1, "pages": 4, "pages_verified": 0,
+                            "verify_syncs": 1}
 
     def test_bound_copy_shares_stats_and_verifies(self):
         chunk, _ = _pages(4, seed=8, zero_every=0)
@@ -315,6 +350,129 @@ class TestEndToEnd:
         with pytest.raises(ChecksumMismatchError):
             orch.restore("m2", pre_install=True)
         orch.close()
+
+
+class TestDeferredVerify:
+    """Bulk installs read their checksums back once, when they end; every
+    other install verifies in its own call (DESIGN.md §13)."""
+
+    @staticmethod
+    def _restore(memory, poison_hot=None):
+        img = _image(seed=17)
+        pool = HierarchicalPool(cxl_capacity=64 << 20, rdma_capacity=64 << 20)
+        master = PoolMaster(pool, Catalog(),
+                            publish_fn=make_fused_publish_fn(use_pallas=False))
+        regions = master.publish("m", img, list(range(0, img.total_pages, 2)))
+        reader = SnapshotReader(regions, pool.host_view("h"), pool.rdma)
+        if poison_hot is not None:
+            _kind, off = reader.lookup(int(reader.hot_page_indices()[poison_hot]))
+            pool.attach_fault_injector(FaultInjector(seed=5).poison_reads(
+                "cxl", 1, lo=off, hi=off + PAGE_SIZE))
+        inst = Instance(StateImage.empty_like(img.manifest))
+        engine = RestoreEngine(reader, inst, None, scatter_fn=_scatter(memory))
+        return img, reader, inst, engine
+
+    @pytest.mark.parametrize("memory", ["host", "device"])
+    def test_bad_page_in_an_early_chunk_is_found_when_the_phase_ends(self, memory):
+        """A poisoned page in the second of several hot chunks: the phase
+        installs every chunk, compares once, and only then repairs the page,
+        which was never present before its repair verified."""
+        img, reader, inst, engine = self._restore(memory, poison_hot=5)
+        target = int(reader.hot_page_indices()[5])
+        n_chunks = len(list(reader.iter_hot_extents(4)))
+        assert n_chunks > 2
+        sf = inst.scatter_fn
+        seen = []
+        repair = engine._repair_page
+
+        def spy(page):
+            seen.append((page, sf.stats["batches"], sf.stats["verify_syncs"],
+                         bool(inst.present[page])))
+            return repair(page)
+
+        engine._repair_page = spy
+        engine.pre_install_hot(chunk_pages=4)
+        assert seen == [(target, n_chunks, 1, False)]
+        # the phase's one readback, then the repair's own single-page check
+        assert sf.stats["verify_syncs"] == 2
+        assert engine.repair_stats["checksum_mismatches"] == 1
+        assert engine.repair_stats["checksum_repairs"] == 1
+        hot = reader.hot_page_indices()
+        assert inst.present[hot].all() and not inst.pending.any()
+        assert inst.stats["uffd_copies"] == hot.size
+        got = inst.image_bytes().reshape(-1, PAGE_SIZE)
+        np.testing.assert_array_equal(got[hot], img.pages_matrix()[hot])
+
+    @pytest.mark.parametrize("memory", ["host", "device"])
+    def test_a_whole_restore_reads_checksums_back_twice(self, memory):
+        """Hot phase, then cold phase: one readback each; the second hot
+        pass finds every page present and holds nothing."""
+        img, reader, inst, engine = self._restore(memory)
+        engine.pre_install_hot(chunk_pages=4)
+        engine.install_all_sync()
+        assert inst.all_present()
+        np.testing.assert_array_equal(inst.image_bytes(), img.buf)
+        assert inst.scatter_fn.stats["verify_syncs"] == 2
+        assert inst.scatter_fn.stats["batches"] > 2
+
+    def test_installs_from_another_thread_verify_in_their_own_call(self):
+        """While a bulk install is open, a completion thread's install is
+        checked at once and its page is present when the call returns; the
+        bulk install's page stays pending until it settles."""
+        img, reader, inst, engine = self._restore("host")
+        hot = reader.hot_page_indices()
+        sf = inst.scatter_fn
+        mat = img.pages_matrix()
+        assert inst.defer_checks()
+        assert not inst.defer_checks()           # one bulk install at a time
+        inst.uffd_copy_batch(hot[:1], mat[hot[:1]])
+        worker = threading.Thread(
+            target=inst.uffd_copy_batch, args=(hot[1:2], mat[hot[1:2]]))
+        worker.start()
+        worker.join(timeout=30)
+        assert not worker.is_alive()
+        assert sf.stats["verify_syncs"] == 1
+        assert inst.present[hot[1]] and not inst.present[hot[0]]
+        assert inst.pending[hot[0]]
+        assert inst.settle_checks().size == 0
+        assert inst.present[hot[:2]].all() and not inst.pending.any()
+        assert sf.stats["verify_syncs"] == 2
+
+
+    def test_faults_racing_a_bulk_install_see_only_verified_pages(self):
+        """Guest threads fault the hot pages while the bulk install walks
+        them one page a chunk: every access returns a present page with the
+        image's bytes, and each page is installed exactly once."""
+        img, reader, inst, engine = self._restore("host")
+        hot = reader.hot_page_indices()
+        want = img.pages_matrix()
+        errors = []
+
+        def guest(order):
+            try:
+                for p in order:
+                    engine.access(int(p), timeout_s=30)
+                    assert inst.present[p]
+                    np.testing.assert_array_equal(inst.image.pages_matrix()[p], want[p])
+            except Exception as e:          # reported by the main thread
+                errors.append(e)
+
+        rng = np.random.default_rng(0)
+        guests = [threading.Thread(target=guest, args=(rng.permutation(hot),))
+                  for _ in range(6)]
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            for t in guests:
+                t.start()
+            engine.pre_install_hot(chunk_pages=1)
+            for t in guests:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in guests) and not errors, errors
+        assert inst.present[hot].all() and not inst.pending.any()
+        assert inst.stats["uffd_copies"] == hot.size
 
 
 class TestZeroScanBackend:

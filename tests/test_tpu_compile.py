@@ -27,7 +27,13 @@ from repro.kernels.snapshot_fuse.kernel import (
     fused_publish_pallas,
     fused_restore_pallas,
 )
-from repro.kernels.snapshot_fuse.ops import MAX_BATCH_PAGES, SLAB_PAGES
+from repro.kernels.snapshot_fuse.ops import (
+    MAX_BATCH_PAGES,
+    SLAB_PAGES,
+    STASH_ENTRIES,
+    STASH_GROUP,
+    _stash,
+)
 from repro.kernels.zero_detect.kernel import zero_detect_pallas
 from repro.models.model_zoo import build
 
@@ -110,6 +116,21 @@ def test_fused_restore_compiles_into_donated_image(one_chip, image_pages, batch)
     m = compiled.memory_analysis()
     assert m.alias_size_in_bytes == image_pages * PAGE_SIZE  # written in place
     assert _device_bytes(compiled) < HBM_BYTES
+
+
+@pytest.mark.parametrize("group", [1, STASH_GROUP])
+@pytest.mark.parametrize("batch", BATCHES)
+def test_checksum_stash_compiles_in_place(one_chip, batch, group):
+    """A bulk install writes its batches' checksums, singly or a group of
+    one shape at a time, into one donated stash vector and its donated
+    write position."""
+    compiled = _stash.lower(
+        _spec(one_chip, (STASH_ENTRIES,), jnp.uint32),
+        _spec(one_chip, (), jnp.int32),
+        *[_spec(one_chip, (batch,), jnp.uint32)] * group).compile()
+    # both are written in place (the scalar takes a padded tile)
+    assert compiled.memory_analysis().alias_size_in_bytes >= 4 * STASH_ENTRIES + 4
+    assert STASH_ENTRIES >= STASH_GROUP * MAX_BATCH_PAGES
 
 
 @pytest.mark.parametrize("kernel", ["zero_detect", "page_checksum"])
