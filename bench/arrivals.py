@@ -10,7 +10,9 @@ seeds do the same work and differ only in their token ids.  A closed loop (``"lo
 invocation when the previous one has answered.  An open loop names an
 arrival process under ``"arrivals"``; :func:`poisson_arrivals` and
 :func:`onoff_arrivals` are copied from ``repro.fleet.arrivals`` so that the
-yardstick does not move when that module changes.
+yardstick does not move when that module changes.  A ``"burst"`` process
+makes one invocation per chip of the cell due together every ``period_s``
+seconds, from t = 0, the same for every seed.
 """
 from __future__ import annotations
 
@@ -58,19 +60,29 @@ def prompts(mix: dict, seed: int, i: int, length: int, vocab: int) -> np.ndarray
     return rng.integers(0, vocab, (mix["batch"], int(length)), dtype=np.int32)
 
 
-def arrivals(mix: dict, seed: int, t_end: float) -> Optional[np.ndarray]:
+def arrivals(mix: dict, seed: int, t_end: float,
+             chips: int = 1) -> Optional[np.ndarray]:
     """Due times (seconds from the window's start) of an open loop, None for
-    a closed loop."""
+    a closed loop, in a cell on ``chips`` chips."""
     if mix["loop"] == "closed":
         return None
     spec = mix["arrivals"]
     rng = _rng(seed, 0xA7)
     if spec["process"] == "poisson":
         return poisson_arrivals(rng, spec["rate_rps"], t_end)
+    if spec["process"] == "burst":
+        return burst_arrivals(chips, spec["period_s"], t_end)
     if spec["process"] == "onoff":
         return onoff_arrivals(rng, spec["rate_rps"], t_end,
                               spec.get("mean_on_s", 2.0), spec.get("mean_off_s", 8.0))
     raise ValueError(f"unknown arrival process {spec['process']!r}")
+
+
+def burst_arrivals(size: int, period_s: float, t_end: float) -> np.ndarray:
+    """``size`` due times at each of 0, ``period_s``, 2 ``period_s``, ...
+    before ``t_end``."""
+    starts = np.arange(0.0, t_end, float(period_s))
+    return np.repeat(starts, int(size))
 
 
 def poisson_arrivals(rng: np.random.Generator, rate_rps: float,

@@ -15,9 +15,15 @@ prefills the batch and reads the first tokens back, then frees the
 instance.  A ``warm`` invocation prefills the same batch on a resident
 server, whose weights are checked against the seed's after the window.  The benchmark's own spans (``bench.*``) wrap each step, so a trace
 can label the device's idle gaps.
+
+A cell on several chips runs cold starts that are due together as one
+burst: one thread per chip, started together, each restoring its instance
+onto its own chip through the one orchestrator (so through one node page
+server and its hot-chunk cache) and serving its prompt there.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import gc
@@ -26,6 +32,7 @@ import json
 import shutil
 import sys
 import tempfile
+import threading
 import time
 from pathlib import Path
 from typing import Any, Callable, Dict, List, Optional
@@ -144,10 +151,21 @@ class Run:
 # the system under test
 # --------------------------------------------------------------------------
 
-class Program:
-    """The program's served path for one configuration."""
+def on_device(device):
+    """JAX's default device for this thread is ``device`` inside the block;
+    ``None`` leaves it as it is."""
+    import jax
 
-    def __init__(self, cfg: dict, mix: dict):
+    return contextlib.nullcontext() if device is None else jax.default_device(device)
+
+
+class Program:
+    """The program's served path for one configuration, on ``devices``: a
+    skeleton pool built on each (so that the instances restored from it, and
+    their decode steps, live there) and one orchestrator for all of them.
+    Without ``devices``, one pool on the default device."""
+
+    def __init__(self, cfg: dict, mix: dict, devices: Optional[list] = None):
         import jax
 
         from repro.launch import serve
@@ -160,10 +178,17 @@ class Program:
         _check_widths(mc, cfg)
         self.mc = mc
         self.serve = serve
-        self.sp = SkeletonPool(mc, batch=mix["batch"], max_len=mix["max_len"],
-                               target_size=1, background=False)
+        self.kind = mix["invocation"]
+        self.devices = list(devices) if devices else [None]
+        self.pools = []
+        for d in self.devices:
+            with on_device(d):
+                self.pools.append(SkeletonPool(mc, batch=mix["batch"],
+                                               max_len=mix["max_len"],
+                                               target_size=1, background=False))
+        self.sp = self.pools[0]
         self.template = jax.eval_shape(self.sp.model.init, jax.random.PRNGKey(0))
-        self.orch = None
+        self.orch = self.node = None
         self.master = None
         self.server = None
         self.pub: Dict[str, int] = {}
@@ -172,12 +197,16 @@ class Program:
     # -- set-up ------------------------------------------------------------
     def publish(self, params) -> None:
         from repro.core import Orchestrator
+        from repro.core.nodeserver import NodePageServer
         from repro.kernels.snapshot_fuse.ops import default_publish_fn
 
         self.master, image, self.pub = self.serve.publish(
             self.mc, params, self.sp.claim().caches, default_publish_fn())
         del image
-        self.orch = Orchestrator("bench-host", self.master.pool, self.master.catalog)
+        # the host's one node page server, as the orchestrator would build it
+        self.node = NodePageServer("bench-host", self.master.pool, buffer_pool_pages=256)
+        self.orch = Orchestrator("bench-host", self.master.pool, self.master.catalog,
+                                 node_server=self.node)
 
     def make_resident(self, params) -> None:
         from repro.serve.engine import ServerInstance
@@ -185,21 +214,61 @@ class Program:
         sk = self.sp.claim()
         self.server = ServerInstance(sk.model, params, sk.caches, sk.max_len)
 
-    def scatter_stats(self) -> Dict[str, int]:
-        sf = self.orch.scatter_fn if self.orch is not None else None
-        return dict(getattr(sf, "stats", {}))
+    def counters(self) -> Dict[str, int]:
+        """The restore's counters so far: the scatter's ``stats``, and the
+        node page server's hot-chunk cache reads, fan-out hits and fan-out
+        installs."""
+        if self.orch is None:
+            return {}
+        out = dict(getattr(self.orch.scatter_fn, "stats", {}))
+        out["chunk_cache_reads"] = self.node.chunks.stats["reads"]
+        out["fanout_hits"] = self.node.chunks.stats["fanout_hits"]
+        out["fanout_installs"] = self.node.stats["fanout_installs"]
+        return out
+
+    def invoke(self, prompts: List[np.ndarray]) -> List[tuple]:
+        """Serve ``prompts[j]`` as one invocation each, the j-th on device
+        j.  One runs in the calling thread; several run as a burst, a thread
+        each, started together.  Returns per invocation what it returned, or
+        the exception it raised, and the host clock when it ended."""
+        call = self.coldstart if self.kind == "coldstart" else self.warm
+
+        def one(j: int) -> tuple:
+            try:
+                out = call(prompts[j], j)
+            except Exception as e:   # an invocation that fails counts as failed
+                out = e
+            return out, time.perf_counter()
+
+        if len(prompts) == 1:
+            return [one(0)]
+        done: List[Optional[tuple]] = [None] * len(prompts)
+        start = threading.Barrier(len(prompts))
+
+        def run(j: int) -> None:
+            with on_device(self.devices[j]):
+                start.wait()
+                done[j] = one(j)
+
+        threads = [threading.Thread(target=run, args=(j,), name=f"bench-burst-{j}")
+                   for j in range(len(prompts))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        return done
 
     # -- one invocation -------------------------------------------------------
     # Prompts arrive as host token ids, as a server receives them; each
     # prefill step takes its column from the host array.
-    def coldstart(self, prompts: np.ndarray):
+    def coldstart(self, prompts: np.ndarray, j: int = 0):
         import jax
         import jax.numpy as jnp
         from jax.profiler import TraceAnnotation
 
         from repro.serve.coldstart import restore_server
 
-        sk = self.sp.claim()
+        sk = self.pools[j].claim()
         t0 = time.perf_counter()
         with TraceAnnotation("bench.restore"):
             out = restore_server(self.orch, self.mc.name, sk, self.template)
@@ -212,7 +281,7 @@ class Program:
         t2 = time.perf_counter()
         return server, logits, tokens, t1 - t0, t2 - t1
 
-    def warm(self, prompts: np.ndarray):
+    def warm(self, prompts: np.ndarray, j: int = 0):
         import jax.numpy as jnp
         from jax.profiler import TraceAnnotation
 
@@ -235,8 +304,10 @@ class Program:
     def close(self) -> None:
         if self.orch is not None:
             self.orch.close()
-        self.sp.close()
-        self.orch = self.master = self.server = None
+            self.node.close()
+        for sp in self.pools:
+            sp.close()
+        self.orch = self.node = self.master = self.server = None
 
 
 def _check_widths(mc, cfg: dict) -> None:
@@ -282,13 +353,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     """Set up, measure, check.  Returns the result line as a dict."""
     import jax
 
-    dev = jax.devices()[0]
+    devices = jax.devices()[:cell.chips]
+    dev = devices[0]
     compiles = CompileCounter()
     cfg, mix = cell.cfg, cell.mix
     kind = mix["invocation"]
     if kind not in ("coldstart", "warm"):
         raise ValueError(f"unknown invocation kind {kind!r}")
-    prog = Program(cfg, mix)
+    if kind == "warm" and cell.chips > 1:
+        raise ValueError("a warm cell serves from one resident server, on one chip")
+    prog = Program(cfg, mix, devices if cell.chips > 1 else None)
     vocab = cfg["vocab_size"]
 
     # -- set-up: weights from the seed, publish or make resident, warm up ----
@@ -297,9 +371,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     if kind == "coldstart":
         prog.publish(params)
         jax.tree.map(lambda x: x.delete(), params)
-        warm_server = prog.coldstart(_warmup_prompts(mix, vocab))[0]
-        prog.release(warm_server)
-        del warm_server
+        # one cold start on each chip, together, as the window runs them
+        warmup = [_warmup_prompts(mix, vocab)] * len(prog.devices)
+        for j, (out, _) in enumerate(prog.invoke(warmup)):
+            if isinstance(out, Exception):
+                raise out
+            if len(prog.devices) > 1:
+                with on_device(prog.devices[j]):    # the window's digest there
+                    wts.digest(out[0].params)
+            prog.release(out[0])
+        del out
     else:
         prog.make_resident(params)
         prog.warm(_warmup_prompts(mix, vocab))
@@ -307,17 +388,18 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     gc.collect()
     setup_s = time.perf_counter() - t_start
     log(f"set-up {setup_s:.3f} s ({compiles.n} compiled, {compiles.hits} from the "
-        f"persistent cache); published "
+        f"persistent cache) on {len(prog.devices)} chip(s); published "
         f"{prog.pub or 'nothing'}")
 
     # -- the window -----------------------------------------------------------
-    before = prog.scatter_stats()
+    before = prog.counters()
     compiles_before = compiles.n + compiles.hits
-    due = arrivals.arrivals(mix, seed, seconds)
+    due = arrivals.arrivals(mix, seed, seconds, cell.chips)
     invs: List[Invocation] = []
     kept = Sample(int(mix["check_invocations"]), seed)
     digests: List[np.ndarray] = []
     failed = 0
+    bursts = 0
     tdir = Path(tempfile.mkdtemp(prefix="bench-trace-")) if trace else None
     n_trace = int(mix["trace_invocations"]) if trace else 0
     traced = _Tracer(tdir) if trace else None
@@ -325,6 +407,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     i = 0
     while True:
         now = time.perf_counter() - w0
+        k = 1                      # invocations due together, one per chip
         if due is None:
             if now >= seconds:
                 break
@@ -333,53 +416,68 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             if i >= due.size:
                 break
             t_due = float(due[i])
+            while (k < len(prog.devices) and i + k < due.size
+                   and due[i + k] == due[i]):
+                k += 1
             if t_due > now:
                 time.sleep(t_due - now)
-        length = arrivals.prompt_length(mix, i)
-        p = arrivals.prompts(mix, seed, i, length, vocab)
+        lengths = [arrivals.prompt_length(mix, i + j) for j in range(k)]
+        ps = [arrivals.prompts(mix, seed, i + j, n, vocab) for j, n in enumerate(lengths)]
         is_traced = i < n_trace
         if is_traced and i == 0:
             traced.start()
-        try:
-            server, logits, tokens, restore_s, first_s = (
-                prog.coldstart(p) if kind == "coldstart" else prog.warm(p))
-        except Exception as e:   # an invocation that fails counts as failed
-            failed += 1
-            log(f"invocation {i} failed: {type(e).__name__}: {e}")
-            i += 1
-            continue
-        # a closed loop times the invocation itself, an open loop from its due
-        # time, so that a stall also counts against the requests behind it
-        latency = ((restore_s or 0.0) + first_s if due is None
-                   else time.perf_counter() - w0 - t_due)
-        if is_traced and i == n_trace - 1:
+        done = prog.invoke(ps)
+        if is_traced and i + k >= n_trace:
             traced.stop()
-        invs.append(Invocation(length, latency, restore_s, first_s, is_traced))
-        kept.offer(i, (p, tokens, logits))
-        if kind == "coldstart":
-            digests.append(wts.digest(server.params))
-            prog.release(server)
-            del server, logits
-            log(f"invocation {i}: {length} tokens, restore {restore_s:.4f} s, "
-                f"first token {first_s:.4f} s")
-        else:
-            log(f"invocation {i}: {length} tokens, dispatched in "
-                f"{prog.dispatch_s:.4f} s, first token {first_s:.4f} s")
-        i += 1
+        bursts += k > 1
+        if len(done) < k:          # due, and never answered: failed
+            failed += k - len(done)
+            log(f"invocations {i + len(done)}..{i + k - 1} were never served")
+        for j, (out, t_done) in enumerate(done):
+            n = i + j
+            if isinstance(out, Exception):
+                failed += 1
+                log(f"invocation {n} failed: {type(out).__name__}: {out}")
+                continue
+            server, logits, tokens, restore_s, first_s = out
+            # a closed loop times the invocation itself, an open loop from its
+            # due time, so that a stall also counts against the requests
+            # behind it
+            latency = ((restore_s or 0.0) + first_s if due is None
+                       else t_done - w0 - t_due)
+            invs.append(Invocation(lengths[j], latency, restore_s, first_s, is_traced))
+            kept.offer(n, (ps[j], tokens, logits))
+            if kind == "coldstart":
+                with on_device(prog.devices[j]):
+                    digests.append(wts.digest(server.params))
+                prog.release(server)
+                log(f"invocation {n}: {lengths[j]} tokens, restore {restore_s:.4f} s, "
+                    f"first token {first_s:.4f} s, latency {latency:.4f} s")
+            else:
+                log(f"invocation {n}: {lengths[j]} tokens, dispatched in "
+                    f"{prog.dispatch_s:.4f} s, first token {first_s:.4f} s")
+            del server, logits, out
+        del done
+        i += k
     window_s = time.perf_counter() - w0
     if traced is not None and traced.on:
         traced.stop()
-    after = prog.scatter_stats()
+    after = prog.counters()
     if kind == "warm":
         digests.append(wts.digest(prog.server.params))
     n_compiles = compiles.n + compiles.hits - compiles_before
     log(f"window {window_s:.3f} s: {len(invs)} invocations, {failed} failed, "
         f"{n_compiles} programs compiled or loaded inside the window")
 
-    stats = dev.memory_stats() or {}
-    mem_peak = stats.get("peak_bytes_in_use")
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
+    mem_peak = max((b for b in peaks if b is not None), default=None)
     counters = {k: after.get(k, 0) - before.get(k, 0) for k in after}
     counters["restores"] = sum(1 for v in invs if v.restore_s is not None)
+    if kind == "coldstart":
+        counters["bursts"] = bursts
+        log(f"hot chunks: {counters['chunk_cache_reads']} read through the cache, "
+            f"{counters['fanout_hits']} handed over, by {counters['restores']} "
+            f"restores in {bursts} bursts; peak HBM per chip {peaks}")
     run = Run(cell, peak, setup_s, window_s, invs, counters,
               work.image_pages(prog.template),
               work.step(prog.template, mix["batch"]), mem_peak)
