@@ -40,6 +40,14 @@ class ModelConfig:
     qk_nope_head_dim: int = 128
     qk_rope_head_dim: int = 64
     v_head_dim: int = 128
+    # YaRN (factor 1: off): frequencies ramp from ÷ factor to the original
+    # between the beta_fast and beta_slow rotation counts at the original
+    # length; softmax scale × (0.1 mscale_all_dim ln factor + 1)²
+    yarn_factor: float = 1.0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_original_len: int = 4096
+    yarn_mscale_all_dim: float = 0.0
 
     # MoE
     n_experts: int = 0
@@ -49,6 +57,19 @@ class ModelConfig:
     n_dense_layers: int = 0           # leading dense FFN layers (DeepSeek: 3)
     capacity_factor: float = 1.25
     mtp: bool = False                 # multi-token prediction head
+    # routing: "softmax" top-k (moe_ffn) or DeepSeek-V3's "sigmoid" noaux_tc
+    # (moe_held): scores sigmoid(x W) in f32, a per-expert bias added for
+    # selection only, experts in moe_groups of which the moe_groups_kept with
+    # the largest top-2 sums are searched, weights from the unbiased scores
+    # over their sum
+    moe_score: str = "softmax"
+    moe_groups: int = 1
+    moe_groups_kept: int = 1
+    moe_scale: float = 1.0            # routed_scaling_factor
+    # expert parallelism: this layer holds experts [first_held_expert,
+    # first_held_expert + held_experts) of n_experts (0: all of them)
+    held_experts: int = 0
+    first_held_expert: int = 0
 
     # SSM / hybrid
     ssm_state: int = 0                # Mamba2 d_state
@@ -87,6 +108,11 @@ class ModelConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head if self.d_head is not None else self.d_model // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        """Routed experts an MoE layer of this config holds."""
+        return self.held_experts or self.n_experts
 
     @property
     def padded_vocab(self) -> int:
@@ -191,7 +217,10 @@ class ModelConfig:
         if self.family == "moe":
             small.update(n_experts=8, top_k=2, d_ff_expert=64,
                          n_shared_experts=min(self.n_shared_experts, 1),
-                         n_dense_layers=min(self.n_dense_layers, 1), n_layers=3)
+                         n_dense_layers=min(self.n_dense_layers, 1), n_layers=3,
+                         held_experts=0, first_held_expert=0)
+            if self.moe_groups > 1:
+                small.update(moe_groups=4, moe_groups_kept=2)
         if self.family in ("ssm", "hybrid"):
             small.update(ssm_state=16, ssm_heads=4, ssm_head_dim=32, ssm_chunk=32)
         if self.family == "hybrid":

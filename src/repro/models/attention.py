@@ -19,7 +19,9 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..kernels.flash_attention.ops import flash_attention
 from ..sharding.partition import cache_constrain, constrain
-from .common import apply_mrope, apply_rope, dense_init
+from ..spans import MLA
+from .common import (apply_mrope, apply_rope, dense_init, init_rmsnorm, rmsnorm, rotate,
+                     yarn_inv_freq, yarn_mscale)
 
 
 # ==========================================================================
@@ -145,13 +147,54 @@ def init_mla(key, cfg: ModelConfig, dtype) -> Dict:
     qr, kr = cfg.q_lora_rank, cfg.kv_lora_rank
     dqn, dqr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     ks = jax.random.split(key, 6)
-    return {
+    p = {
         "wq_a": dense_init(ks[0], (d, qr), dtype),                      # down
         "wq_b": dense_init(ks[1], (qr, h * (dqn + dqr)), dtype, fan_in=qr),
         "wkv_a": dense_init(ks[2], (d, kr + dqr), dtype),               # latent + rope-k
         "wkv_b": dense_init(ks[3], (kr, h * (dqn + dv)), dtype, fan_in=kr),
         "wo": dense_init(ks[4], (h * dv, d), dtype, fan_in=h * dv),
     }
+    p["q_norm"] = init_rmsnorm(qr, dtype)
+    p["kv_norm"] = init_rmsnorm(kr, dtype)
+    return p
+
+
+def mla_rope(x: jnp.ndarray, positions: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """Rotary embedding of MLA's rope dims x (..., S, dqr), on interleaved
+    pairs (2i, 2i + 1) as the published code turns them, with YaRN
+    frequencies when the config scales them.  The pairs are first gathered
+    into halves, as HF's modelling code does, so q and k come out in the
+    same (half-split) order."""
+    d = x.shape[-1]
+    x = x.reshape(*x.shape[:-1], d // 2, 2).swapaxes(-1, -2).reshape(x.shape)
+    inv = yarn_inv_freq(d, cfg.rope_theta, cfg.yarn_factor, cfg.yarn_beta_fast,
+                        cfg.yarn_beta_slow, cfg.yarn_original_len)
+    return rotate(x, positions[..., None].astype(jnp.float32) * jnp.asarray(inv))
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """(nope + rope)^-0.5 × YaRN's mscale² (1 where unscaled)."""
+    m = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def _mla_q(params, x, positions, cfg: ModelConfig):
+    """Queries (B, h, S, ·): the nope part and the rotated rope part."""
+    b, s, _ = x.shape
+    dqn, dqr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = rmsnorm(params["q_norm"], jnp.einsum("bsd,dr->bsr", x, params["wq_a"]), cfg.norm_eps)
+    q = jnp.einsum("bsr,re->bse", q, params["wq_b"]).reshape(b, s, cfg.n_heads, dqn + dqr)
+    q = q.transpose(0, 2, 1, 3)
+    return q[..., :dqn], mla_rope(q[..., dqn:], positions, cfg)
+
+
+def _mla_entry(params, x, positions, cfg: ModelConfig):
+    """What the latent cache holds per token (B, S, kv_lora + rope): the
+    normed kv latent and the rotated rope key."""
+    kr = cfg.kv_lora_rank
+    kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
+    latent = rmsnorm(params["kv_norm"], kv[..., :kr], cfg.norm_eps)
+    return jnp.concatenate([latent, mla_rope(kv[..., kr:], positions, cfg)], axis=-1)
 
 
 def _mla_qkv(params, x, positions, cfg: ModelConfig):
@@ -160,22 +203,16 @@ def _mla_qkv(params, x, positions, cfg: ModelConfig):
     dqn, dqr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     kr = cfg.kv_lora_rank
 
-    q = jnp.einsum("bsd,dr->bsr", x, params["wq_a"])
-    q = jnp.einsum("bsr,re->bse", q, params["wq_b"]).reshape(b, s, h, dqn + dqr)
-    q = q.transpose(0, 2, 1, 3)
-    q_nope, q_rope = q[..., :dqn], q[..., dqn:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-
-    kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])
-    latent, k_rope = kv[..., :kr], kv[..., kr:]
-    k_rope = apply_rope(k_rope[:, None], positions, cfg.rope_theta)  # (B,1,S,dqr)
+    q_nope, q_rope = _mla_q(params, x, positions, cfg)
+    entry = _mla_entry(params, x, positions, cfg)
+    latent, k_rope = entry[..., :kr], entry[:, None, :, kr:]          # (B,1,S,dqr)
 
     kvu = jnp.einsum("bsr,re->bse", latent, params["wkv_b"]).reshape(b, s, h, dqn + dv)
     kvu = kvu.transpose(0, 2, 1, 3)
     k_nope, v = kvu[..., :dqn], kvu[..., dqn:]
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, h, s, dqr))], axis=-1)
     qq = jnp.concatenate([q_nope, q_rope], axis=-1)
-    return qq, k, v, latent, k_rope
+    return qq, k, v
 
 
 def mla_attention(params: Dict, x: jnp.ndarray, cfg: ModelConfig,
@@ -184,15 +221,27 @@ def mla_attention(params: Dict, x: jnp.ndarray, cfg: ModelConfig,
     b, s, _ = x.shape
     if positions is None:
         positions = jnp.arange(s)
-    q, k, v, _, _ = _mla_qkv(params, x, positions, cfg)
-    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
-    o = flash_attention(q, k, v, causal=causal, scale=scale)
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.v_head_dim)
-    return jnp.einsum("bse,ed->bsd", o, params["wo"])
+    with jax.named_scope(MLA):
+        q, k, v = _mla_qkv(params, x, positions, cfg)
+        o = flash_attention(q, k, v, causal=causal, scale=mla_softmax_scale(cfg))
+        o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.v_head_dim)
+        return jnp.einsum("bse,ed->bsd", o, params["wo"])
 
 
 def init_mla_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
     return {"latent": jnp.zeros((batch, max_len, cfg.kv_lora_rank + cfg.qk_rope_head_dim), dtype)}
+
+
+def _mla_step(params: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
+              cfg: ModelConfig):
+    """One token's queries, and the latent cache with its entry written at
+    ``pos``."""
+    q_nope, q_rope = _mla_q(params, x, pos[None], cfg)          # (B,h,1,·)
+    entry = _mla_entry(params, x, pos[None], cfg)                # (B,1,kr+dqr)
+    lat = cache_constrain(jax.lax.dynamic_update_slice(
+        cache["latent"], entry.astype(cache["latent"].dtype), (0, pos, 0)
+    ), seq_shard=cfg.flash_decoding)
+    return q_nope, q_rope, lat
 
 
 def mla_decode_absorbed(params: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
@@ -204,21 +253,10 @@ def mla_decode_absorbed(params: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndar
     traffic drops from O(S·h·(dqn+dv)) re-expansion to O(S·kr)."""
     b = x.shape[0]
     h = cfg.n_heads
-    dqn, dqr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dqn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
     kr = cfg.kv_lora_rank
 
-    q = jnp.einsum("bsd,dr->bsr", x, params["wq_a"])
-    q = jnp.einsum("bsr,re->bse", q, params["wq_b"]).reshape(b, 1, h, dqn + dqr)
-    q = q.transpose(0, 2, 1, 3)                                # (B,h,1,·)
-    q_nope, q_rope = q[..., :dqn], q[..., dqn:]
-    q_rope = apply_rope(q_rope, pos[None], cfg.rope_theta)
-
-    kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])         # (B,1,kr+dqr)
-    k_rope_new = apply_rope(kv[:, None, :, kr:], pos[None], cfg.rope_theta)
-    entry = jnp.concatenate([kv[..., :kr], k_rope_new[:, 0]], axis=-1)
-    lat = cache_constrain(jax.lax.dynamic_update_slice(
-        cache["latent"], entry.astype(cache["latent"].dtype), (0, pos, 0)
-    ), seq_shard=cfg.flash_decoding)
+    q_nope, q_rope, lat = _mla_step(params, x, cache, pos, cfg)
     latent_all, k_rope_all = lat[..., :kr], lat[..., kr:]
 
     wkv_b = params["wkv_b"].reshape(kr, h, dqn + dv)
@@ -229,8 +267,7 @@ def mla_decode_absorbed(params: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndar
                         latent_all, preferred_element_type=jnp.float32)
     s_rope = jnp.einsum("bhqd,bsd->bhs", q_rope, k_rope_all,
                         preferred_element_type=jnp.float32)
-    sc = (dqn + dqr) ** -0.5
-    s = (s_nope + s_rope) * sc
+    s = (s_nope + s_rope) * mla_softmax_scale(cfg)
     valid = jnp.arange(lat.shape[1])[None, None, :] <= pos
     s = jnp.where(valid, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)                              # (B,h,S)
@@ -242,40 +279,26 @@ def mla_decode_absorbed(params: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndar
     return jnp.einsum("bse,ed->bsd", o, params["wo"]), {"latent": lat}
 
 
-def mla_decode(params: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
-               cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
+def mla_decode_expanded(params: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
+                        cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
     """Latent-cache decode: re-expands K/V from the cached latent (B,S,kr)."""
-    if cfg.mla_absorb:
-        return mla_decode_absorbed(params, x, cache, pos, cfg)
     b = x.shape[0]
     h = cfg.n_heads
-    dqn, dqr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    dqn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
     kr = cfg.kv_lora_rank
 
-    q = jnp.einsum("bsd,dr->bsr", x, params["wq_a"])
-    q = jnp.einsum("bsr,re->bse", q, params["wq_b"]).reshape(b, 1, h, dqn + dqr)
-    q = q.transpose(0, 2, 1, 3)
-    q_nope, q_rope = q[..., :dqn], q[..., dqn:]
-    q_rope = apply_rope(q_rope, pos[None], cfg.rope_theta)
-
-    kv = jnp.einsum("bsd,dr->bsr", x, params["wkv_a"])          # (B,1,kr+dqr)
-    k_rope_new = apply_rope(kv[:, None, :, kr:], pos[None], cfg.rope_theta)
-    entry = jnp.concatenate([kv[..., :kr], k_rope_new[:, 0]], axis=-1)
-    lat = cache_constrain(jax.lax.dynamic_update_slice(
-        cache["latent"], entry.astype(cache["latent"].dtype), (0, pos, 0)
-    ), seq_shard=cfg.flash_decoding)
+    q_nope, q_rope, lat = _mla_step(params, x, cache, pos, cfg)
     latent_all, k_rope_all = lat[..., :kr], lat[..., kr:]
 
     kvu = jnp.einsum("bsr,re->bse", latent_all, params["wkv_b"].astype(cache["latent"].dtype))
     kvu = kvu.reshape(b, -1, h, dqn + dv).transpose(0, 2, 1, 3)   # (B,h,S,dqn+dv)
     k_nope, v = kvu[..., :dqn], kvu[..., dqn:]
 
-    sc = (dqn + dqr) ** -0.5
     s_nope = jnp.einsum("bhqd,bhsd->bhqs", q_nope, k_nope,
                         preferred_element_type=jnp.float32)
     s_rope = jnp.einsum("bhqd,bsd->bhqs", q_rope, k_rope_all,
                         preferred_element_type=jnp.float32)
-    s = (s_nope + s_rope) * sc
+    s = (s_nope + s_rope) * mla_softmax_scale(cfg)
     valid = jnp.arange(lat.shape[1])[None, None, None, :] <= pos
     s = jnp.where(valid, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
@@ -283,6 +306,15 @@ def mla_decode(params: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
                    preferred_element_type=jnp.float32)
     o = o.transpose(0, 2, 1, 3).reshape(b, 1, h * dv).astype(x.dtype)
     return jnp.einsum("bse,ed->bsd", o, params["wo"]), {"latent": lat}
+
+
+def mla_decode(params: Dict, x: jnp.ndarray, cache: Dict, pos: jnp.ndarray,
+               cfg: ModelConfig) -> Tuple[jnp.ndarray, Dict]:
+    """One token through the latent cache: weight-absorbed (``mla_absorb``)
+    or with K/V re-expanded."""
+    with jax.named_scope(MLA):
+        step = mla_decode_absorbed if cfg.mla_absorb else mla_decode_expanded
+        return step(params, x, cache, pos, cfg)
 
 
 # ==========================================================================

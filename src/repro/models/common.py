@@ -9,6 +9,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 # --------------------------------------------------------------------------
@@ -53,11 +54,44 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
     """x: (..., S, D); positions: broadcastable to (..., S). Half-split RoPE."""
     d = x.shape[-1]
     freqs = rope_freqs(d, theta)                       # (D/2,)
-    angles = positions[..., None].astype(jnp.float32) * freqs  # (..., S, D/2)
+    return rotate(x, positions[..., None].astype(jnp.float32) * freqs)  # (..., S, D/2)
+
+
+def rotate(x: jnp.ndarray, angles: jnp.ndarray) -> jnp.ndarray:
+    """Half-split rotation of x (..., S, D) by ``angles`` (..., S, D/2)."""
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention factor: 0.1 · mscale · ln(factor) + 1 (1 without
+    scaling)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(d: int, theta: float, factor: float, beta_fast: float,
+                  beta_slow: float, original_len: int) -> np.ndarray:
+    """YaRN inverse frequencies of a D-wide rotary head, as DeepSeek-V3's
+    published code computes them: the dims whose rotations at
+    ``original_len`` fall between ``beta_fast`` and ``beta_slow`` ramp
+    linearly from the original frequency to the interpolated one (÷
+    ``factor``); faster dims keep the original, slower ones interpolate."""
+    extra = 1.0 / theta ** (np.arange(0, d, 2, dtype=np.float32) / d)
+    if factor <= 1:
+        return extra
+
+    def dim_of(rotations):
+        return d * math.log(original_len / (rotations * 2 * math.pi)) / (2 * math.log(theta))
+
+    low = max(math.floor(dim_of(beta_fast)), 0)
+    high = min(math.ceil(dim_of(beta_slow)), d - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(d // 2, dtype=np.float32) - low) / (high - low), 0, 1)
+    keep = 1.0 - ramp                  # 1: the original frequency
+    return (extra / factor * (1 - keep) + extra * keep).astype(np.float32)
 
 
 def mrope_positions(
@@ -166,7 +200,12 @@ def unembed(params: Dict, x: jnp.ndarray, logits_fp32: bool = True,
     return out.astype(jnp.float32) if logits_fp32 else out
 
 
-def cast_tree(tree, dtype):
+def cast_tree(tree, dtype, keep=()):
+    """Floating leaves in ``dtype``, but those named in ``keep``."""
+    if keep:
+        return jax.tree_util.tree_map_with_path(
+            lambda path, x: x if getattr(path[-1], "key", None) in keep
+            else cast_tree(x, dtype), tree)
     return jax.tree.map(
         lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x, tree
     )

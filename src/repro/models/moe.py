@@ -1,10 +1,17 @@
-"""Mixture-of-Experts FFN: shared experts + routed top-k with capacity-factor
-dispatch/combine einsums (GShard-style "dropping" baseline).
+"""Mixture-of-Experts FFNs.
 
-This is deliberately the *baseline* formulation — the §Perf hillclimb swaps
-the (tokens, experts, capacity) dispatch for a sort-based formulation and
-records the delta.  Router softmax runs in f32; an auxiliary load-balancing
-loss (Switch-style) is returned for the train step.
+``moe_ffn``: shared experts + softmax top-k with capacity-factor
+dispatch/combine einsums (GShard-style "dropping" baseline); the §Perf
+hillclimb swaps the (tokens, experts, capacity) dispatch for a sort-based
+formulation (``moe_ffn_sorted``).  Router softmax runs in f32; an auxiliary
+load-balancing loss (Switch-style) is returned for the train step.
+
+``moe_held``: DeepSeek-V3's layer as one rank of expert parallelism runs it.
+The router scores all ``n_experts`` (sigmoid, f32, group-limited, a bias for
+the choice only); the layer holds ``cfg.n_held`` of them and computes, with
+no capacity and no drop, each token's part from the held experts it chose,
+plus the shared expert.  What experts held elsewhere add is left out: on one
+chip there is no exchange to stand in for.
 """
 from __future__ import annotations
 
@@ -15,21 +22,105 @@ import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
 from ..sharding.partition import constrain
+from ..spans import MOE_EXPERTS, MOE_ROUTE, MOE_SHARED
 from .common import dense_init, init_mlp, mlp
 
 
 def init_moe(key, cfg: ModelConfig, dtype) -> Dict:
-    d, fe, e = cfg.d_model, cfg.d_ff_expert, cfg.n_experts
+    """The router over all ``n_experts`` (f32) and the ``n_held`` experts
+    this layer holds.  DeepSeek's bias is an ``(n_experts, 1)`` leaf."""
+    d, fe, e, n = cfg.d_model, cfg.d_ff_expert, cfg.n_experts, cfg.n_held
     ks = jax.random.split(key, 5)
     p = {
         "router": dense_init(ks[0], (d, e), jnp.float32),
-        "wi": dense_init(ks[1], (e, d, fe), dtype),
-        "wg": dense_init(ks[2], (e, d, fe), dtype),
-        "wo": dense_init(ks[3], (e, fe, d), dtype, fan_in=fe),
+        "wi": dense_init(ks[1], (n, d, fe), dtype),
+        "wg": dense_init(ks[2], (n, d, fe), dtype),
+        "wo": dense_init(ks[3], (n, fe, d), dtype, fan_in=fe),
     }
+    if cfg.moe_score == "sigmoid":
+        p["bias"] = jnp.zeros((e, 1), jnp.float32)
     if cfg.n_shared_experts:
         p["shared"] = init_mlp(ks[4], d, fe * cfg.n_shared_experts, dtype)
     return p
+
+
+def router_scores(logits: jnp.ndarray) -> jnp.ndarray:
+    return jax.nn.sigmoid(logits)
+
+
+def route(params: Dict, x: jnp.ndarray, cfg: ModelConfig
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """noaux_tc routing of tokens x (t, D) in f32: the experts each token
+    chooses (t, top_k) and their weights (t, top_k).  The bias moves the
+    choice only; a group's score is the sum of its two best biased scores,
+    and the top experts are searched in the ``moe_groups_kept`` best
+    groups.  Weights are the chosen unbiased scores, divided by their sum,
+    × ``moe_scale``."""
+    scores = router_scores(jnp.einsum("td,de->te", x.astype(jnp.float32),
+                                      params["router"].astype(jnp.float32)))
+    choice = scores + params["bias"][:, 0]
+    t, e = scores.shape
+    g = cfg.moe_groups
+    if g > 1:
+        best2 = jax.lax.top_k(choice.reshape(t, g, e // g), 2)[0].sum(-1)   # (t, g)
+        _, kept = jax.lax.top_k(best2, cfg.moe_groups_kept)
+        searched = jnp.zeros((t, g), bool).at[jnp.arange(t)[:, None], kept].set(True)
+        choice = jnp.where(jnp.repeat(searched, e // g, axis=1), choice, -jnp.inf)
+    _, idx = jax.lax.top_k(choice, cfg.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=1)
+    return idx, w / w.sum(-1, keepdims=True) * cfg.moe_scale
+
+
+def held_experts(params: Dict, x: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
+                 cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of the result, f32 (t, D), and the number of
+    tokens routed to at least one of them.  No token is dropped.
+
+    A decode step's few tokens (no more choices than held experts) go
+    through every held expert, weighted by zero where not chosen: the step
+    reads each held expert's weights whole either way, and the products read
+    them in place from the stacked layers.  Longer inputs run each (token,
+    choice) routed to a held expert once, grouped by expert (``ragged_dot``);
+    the rest sort last, outside every group, and add nothing."""
+    t, k = idx.shape
+    n = cfg.n_held
+    local = idx - cfg.first_held_expert
+    held = (local >= 0) & (local < n)
+    n_held = held.any(-1).sum(dtype=jnp.int32)
+    if t * k <= n:
+        weight = (w[..., None] * (local[..., None] == jnp.arange(n))).sum(1)   # (t, n)
+        h = jnp.einsum("td,edf->tef", x, params["wi"])
+        gate = jnp.einsum("td,edf->tef", x, params["wg"])
+        out = jnp.einsum("tef,efd->ted", jax.nn.silu(gate) * h, params["wo"],
+                         preferred_element_type=jnp.float32)
+        return jnp.einsum("te,ted->td", weight, out), n_held
+    flat = jnp.where(held, local, n).reshape(t * k)
+    order = jnp.argsort(flat)                                # stable
+    sizes = jnp.bincount(flat, length=n + 1)[:n].astype(jnp.int32)
+    xs = jnp.take(x, order // k, axis=0)
+    h = jax.lax.ragged_dot(xs, params["wi"], sizes)
+    gate = jax.lax.ragged_dot(xs, params["wg"], sizes)
+    out = jax.lax.ragged_dot(jax.nn.silu(gate) * h, params["wo"], sizes,
+                             preferred_element_type=jnp.float32)
+    routed = jnp.arange(t * k) < sizes.sum()
+    part = jnp.where(routed[:, None], out * w.reshape(t * k)[order][:, None], 0.0)
+    return jnp.zeros((t, x.shape[-1]), jnp.float32).at[order // k].add(part), n_held
+
+
+def moe_held(params: Dict, x: jnp.ndarray, cfg: ModelConfig
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x: (B, S, D) -> (y, tokens routed to a held expert).  Routing weights
+    stay f32 through the combine; y is cast to x's dtype at the end."""
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    with jax.named_scope(MOE_ROUTE):
+        idx, w = route(params, xt, cfg)
+    with jax.named_scope(MOE_EXPERTS):
+        y, n_held = held_experts(params, xt, idx, w, cfg)
+    if cfg.n_shared_experts:
+        with jax.named_scope(MOE_SHARED):
+            y = y + mlp(params["shared"], xt).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(b, s, d), n_held
 
 
 def moe_ffn_sorted(params: Dict, x: jnp.ndarray, cfg: ModelConfig

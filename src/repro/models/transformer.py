@@ -34,7 +34,7 @@ from .common import (
     rmsnorm,
     unembed,
 )
-from .moe import init_moe, moe_ffn
+from .moe import init_moe, moe_ffn, moe_held
 
 
 # --------------------------------------------------------------------------
@@ -62,17 +62,17 @@ def _res_constrain(x, cfg: ModelConfig):
 
 def dense_block(params, x, cfg: ModelConfig, positions=None, mrope_pos=None):
     x = _res_constrain(x, cfg)
-    x = x + attn_mod.attention(params["attn"], rmsnorm(params["ln1"], x), cfg,
+    x = x + attn_mod.attention(params["attn"], rmsnorm(params["ln1"], x, cfg.norm_eps), cfg,
                                positions, mrope_pos)
-    x = x + mlp(params["mlp"], rmsnorm(params["ln2"], x))
+    x = x + mlp(params["mlp"], rmsnorm(params["ln2"], x, cfg.norm_eps))
     return _res_constrain(x, cfg)
 
 
 def dense_block_decode(params, x, cache, pos, cfg: ModelConfig, mrope_pos3=None):
-    h, cache = attn_mod.decode(params["attn"], rmsnorm(params["ln1"], x), cache, pos, cfg,
-                               mrope_pos3=mrope_pos3)
+    h, cache = attn_mod.decode(params["attn"], rmsnorm(params["ln1"], x, cfg.norm_eps), cache,
+                               pos, cfg, mrope_pos3=mrope_pos3)
     x = x + h
-    x = x + mlp(params["mlp"], rmsnorm(params["ln2"], x))
+    x = x + mlp(params["mlp"], rmsnorm(params["ln2"], x, cfg.norm_eps))
     return x, cache
 
 
@@ -86,18 +86,43 @@ def init_moe_block(key, cfg: ModelConfig, dtype) -> Dict:
     }
 
 
+def _held(cfg: ModelConfig) -> bool:
+    """DeepSeek's sigmoid router runs the held-share layer (``moe_held``);
+    softmax routers the capacity baseline (``moe_ffn``)."""
+    return cfg.family == "moe" and cfg.moe_score == "sigmoid"
+
+
+def _keep_f32(cfg: ModelConfig):
+    """Leaves the compute-dtype cast leaves as they are: the held layer's
+    router and bias run in f32."""
+    return ("router", "bias") if _held(cfg) else ()
+
+
 def moe_block(params, x, cfg: ModelConfig, positions=None):
     x = _res_constrain(x, cfg)
-    x = x + attn_mod.attention(params["attn"], rmsnorm(params["ln1"], x), cfg, positions)
-    y, aux = moe_ffn(params["moe"], rmsnorm(params["ln2"], x), cfg)
+    x = x + attn_mod.attention(params["attn"], rmsnorm(params["ln1"], x, cfg.norm_eps),
+                               cfg, positions)
+    h = rmsnorm(params["ln2"], x, cfg.norm_eps)
+    if _held(cfg):
+        y, _ = moe_held(params["moe"], h, cfg)
+        aux = jnp.zeros((), jnp.float32)
+    else:
+        y, aux = moe_ffn(params["moe"], h, cfg)
     return _res_constrain(x + y, cfg), aux
 
 
 def moe_block_decode(params, x, cache, pos, cfg: ModelConfig):
-    h, cache = attn_mod.decode(params["attn"], rmsnorm(params["ln1"], x), cache, pos, cfg)
+    """-> (x, cache, tokens routed to a held expert; 0 from ``moe_ffn``)."""
+    h, cache = attn_mod.decode(params["attn"], rmsnorm(params["ln1"], x, cfg.norm_eps),
+                               cache, pos, cfg)
     x = x + h
-    y, _ = moe_ffn(params["moe"], rmsnorm(params["ln2"], x), cfg, group_size=x.shape[0])
-    return x + y, cache
+    h = rmsnorm(params["ln2"], x, cfg.norm_eps)
+    if _held(cfg):
+        y, n_held = moe_held(params["moe"], h, cfg)
+    else:
+        y, _ = moe_ffn(params["moe"], h, cfg, group_size=x.shape[0])
+        n_held = jnp.zeros((), jnp.int32)
+    return x + y, cache, n_held
 
 
 def init_xlstm_pair(key, cfg: ModelConfig, dtype) -> Dict:
@@ -225,7 +250,7 @@ def _lm_trunk(
         mrope_pos = mrope_positions(s, cfg.vision_prefix, cfg.vision_grid)
     positions = jnp.arange(s)
     aux = jnp.zeros((), jnp.float32)
-    cparams = cast_tree(params, cdt)
+    cparams = cast_tree(params, cdt, keep=_keep_f32(cfg))
     fam = cfg.family
 
     if fam in ("dense", "vlm"):
@@ -290,7 +315,7 @@ def lm_forward(
     """tokens: (B, S) -> (logits (B, S, V) f32, aux_loss scalar)."""
     h, aux = _lm_trunk(params, tokens, cfg, vision_embeds)
     cparams = cast_tree(params, cfg.cdtype())
-    h = rmsnorm(cparams["final_norm"], h)
+    h = rmsnorm(cparams["final_norm"], h, cfg.norm_eps)
     logits = unembed(cparams["embed"], h, cfg.logits_fp32, vocab=cfg.vocab)
     return logits, aux
 
@@ -301,7 +326,7 @@ def lm_forward_mtp(params: Dict, tokens: jnp.ndarray, cfg: ModelConfig):
     cdt = cfg.cdtype()
     h_trunk, aux = _lm_trunk(params, tokens, cfg)
     cparams = cast_tree(params, cdt)
-    hn = rmsnorm(cparams["final_norm"], h_trunk)
+    hn = rmsnorm(cparams["final_norm"], h_trunk, cfg.norm_eps)
     logits = unembed(cparams["embed"], hn, cfg.logits_fp32, vocab=cfg.vocab)
     if not cfg.mtp:
         return logits, None, aux
@@ -309,11 +334,11 @@ def lm_forward_mtp(params: Dict, tokens: jnp.ndarray, cfg: ModelConfig):
     nxt = jnp.concatenate([x[:, 1:], x[:, -1:]], axis=1)   # emb(token_{t+1})
     m = cparams["mtp"]
     comb = jnp.concatenate(
-        [rmsnorm(m["norm_h"], h_trunk), rmsnorm(m["norm_e"], nxt)], axis=-1
-    )
+        [rmsnorm(m["norm_h"], h_trunk, cfg.norm_eps), rmsnorm(m["norm_e"], nxt, cfg.norm_eps)],
+        axis=-1)
     h = jnp.einsum("bse,ed->bsd", comb, m["proj"])
     h = dense_block(m["block"], h, cfg, jnp.arange(tokens.shape[1]))
-    mtp_logits = unembed(cparams["embed"], rmsnorm(cparams["final_norm"], h),
+    mtp_logits = unembed(cparams["embed"], rmsnorm(cparams["final_norm"], h, cfg.norm_eps),
                          cfg.logits_fp32, vocab=cfg.vocab)
     return logits, mtp_logits, aux
 
@@ -321,6 +346,11 @@ def lm_forward_mtp(params: Dict, tokens: jnp.ndarray, cfg: ModelConfig):
 # --------------------------------------------------------------------------
 # decode
 # --------------------------------------------------------------------------
+
+# the held-share layer's decode state counts, on the device, the (token, MoE
+# layer) pairs routed to at least one held expert since the caches were made
+HELD_EXPERT_TOKENS = "held_expert_tokens"
+
 
 def init_lm_caches(cfg: ModelConfig, batch: int, max_len: int):
     cdt = cfg.cdtype()
@@ -340,6 +370,8 @@ def init_lm_caches(cfg: ModelConfig, batch: int, max_len: int):
         if cfg.n_dense_layers:
             caches["dense"] = [attn_mod.init_cache(cfg, batch, max_len, cdt)
                                for _ in range(cfg.n_dense_layers)]
+        if _held(cfg):
+            caches[HELD_EXPERT_TOKENS] = jnp.zeros((), jnp.int32)
         return caches
     if fam == "ssm":
         return stack(cfg.n_layers // 2, lambda: {
@@ -360,7 +392,7 @@ def lm_decode_step(params: Dict, tokens: jnp.ndarray, caches, pos: jnp.ndarray,
                    cfg: ModelConfig):
     """tokens: (B, 1) new token ids; pos: scalar index. -> (logits, caches)."""
     cdt = cfg.cdtype()
-    cparams = cast_tree(params, cdt)
+    cparams = cast_tree(params, cdt, keep=_keep_f32(cfg))
     x = embed(cparams["embed"], tokens, cdt)
     fam = cfg.family
     mrope_pos3 = None
@@ -386,14 +418,18 @@ def lm_decode_step(params: Dict, tokens: jnp.ndarray, caches, pos: jnp.ndarray,
         for p, c in zip(cparams.get("dense_layers", []), caches.get("dense", [])):
             x, c = dense_block_decode(p, x, c, pos, cfg)
             new_dense.append(c)
-        def step(h, pc):
+        def step(carry, pc):
             p, c = pc
-            h, c = moe_block_decode(p, h, c, pos, cfg)
-            return h, c
-        x, scan_caches = jax.lax.scan(step, x, (cparams["layers"], caches["scan"]))
+            h, c, n = moe_block_decode(p, carry[0], c, pos, cfg)
+            return (h, carry[1] + n), c
+        (x, n_held), scan_caches = jax.lax.scan(
+            step, (x, jnp.zeros((), jnp.int32)), (cparams["layers"], caches["scan"]))
+        held_total = caches.get(HELD_EXPERT_TOKENS)
         caches = {"scan": scan_caches}
         if new_dense:
             caches["dense"] = new_dense
+        if held_total is not None:
+            caches[HELD_EXPERT_TOKENS] = held_total + n_held
     elif fam == "ssm":
         def step(h, pc):
             p, c = pc
@@ -416,6 +452,6 @@ def lm_decode_step(params: Dict, tokens: jnp.ndarray, caches, pos: jnp.ndarray,
     else:
         raise ValueError(fam)
 
-    x = rmsnorm(cparams["final_norm"], x)
+    x = rmsnorm(cparams["final_norm"], x, cfg.norm_eps)
     logits = unembed(cparams["embed"], x, cfg.logits_fp32, vocab=cfg.vocab)
     return logits, caches

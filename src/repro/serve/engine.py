@@ -15,6 +15,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig
 from ..models.model_zoo import Model, build
+from ..models.transformer import HELD_EXPERT_TOKENS
 from ..spans import SERVE_PREFILL, spanned
 
 
@@ -35,7 +36,24 @@ class ServerInstance:
             self.model, self.params, tokens, self.caches, self.pos
         )
         self.pos += tokens.shape[1]
+        held = self._held_expert_tokens()
+        if held is not None:
+            # on its way to the host behind the logits, so that reading
+            # ``stats`` once they are back waits on nothing
+            held.copy_to_host_async()
         return logits
+
+    def _held_expert_tokens(self):
+        return (self.caches.get(HELD_EXPERT_TOKENS)
+                if isinstance(self.caches, dict) else None)
+
+    @property
+    def stats(self) -> Dict[str, int]:
+        """The decode state's counters: ``held_expert_tokens``, the (token,
+        MoE layer) pairs routed to a held expert since the caches were made
+        (models with the held-share MoE layer only)."""
+        held = self._held_expert_tokens()
+        return {} if held is None else {HELD_EXPERT_TOKENS: int(held)}
 
     def decode(self, tokens: jnp.ndarray) -> jnp.ndarray:
         """One step: tokens (B, 1) -> logits (B, V)."""

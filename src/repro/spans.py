@@ -35,6 +35,13 @@ SCATTER_LAUNCH = PREFIX + "scatter.launch"        # kernel dispatch (+ checksum 
 SCATTER_VERIFY = PREFIX + "scatter.verify"        # checksum readback, compare: per call or bulk phase
 SERVE_PREFILL = PREFIX + "serve.prefill"          # ServerInstance.prefill
 
+# ``jax.named_scope`` names inside the model's programs: they prefix the
+# names of the operations they trace, not host spans
+MLA = PREFIX + "mla"                              # latent attention, a layer
+MOE_ROUTE = PREFIX + "moe.route"                  # scores, groups, top-k, weights
+MOE_EXPERTS = PREFIX + "moe.experts"              # the held routed experts
+MOE_SHARED = PREFIX + "moe.shared"                # the shared expert
+
 try:
     from jax.profiler import TraceAnnotation as _Annotation
 except ImportError:      # the host-only pool runs without JAX

@@ -17,7 +17,7 @@ SPANS_PY = Path(spans.__file__)
 def test_names_are_constants_under_one_prefix():
     names = [v for k, v in vars(spans).items()
              if k.isupper() and k != "PREFIX" and isinstance(v, str)]
-    assert len(names) == len(set(names)) == 13
+    assert len(names) == len(set(names)) == 17
     assert all(n.startswith("aquifer.") for n in names)
 
 

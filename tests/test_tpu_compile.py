@@ -163,3 +163,30 @@ def test_flash_attention_compiles_at_phi4_widths(one_chip, sq, skv):
     q = _spec(one_chip, (1, cfg.n_heads, sq, hd), jnp.bfloat16)
     kv = _spec(one_chip, (1, cfg.n_kv_heads, skv, hd), jnp.bfloat16)
     _compiled(flash_attention_pallas, q, kv, kv)
+
+
+def test_deepseek_decode_step_compiles_on_one_chip_share(one_chip):
+    """DeepSeek-V3's decode step as one chip of its EP32 prefill unit holds
+    it (published widths; 1 dense + 4 MoE layers, 32 of 128 heads, 8 of 256
+    experts held, 16,160 vocabulary rows) fits with its 8,192-row latent
+    cache, and reads the held experts' stacks in place: no temporary holds
+    a layer's copy of them (235 MB each)."""
+    cfg = dataclasses.replace(get_config("deepseek-v3-671b"), n_layers=5, n_dense_layers=1,
+                              n_heads=32, n_kv_heads=32, vocab=16160, held_experts=8,
+                              mtp=False, mla_absorb=True, param_dtype="bfloat16")
+    model = build(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(lambda: model.init_caches(None, 1, 8192)))
+
+    def decode_step(params, tokens, caches, pos):
+        return model.decode_step(params, {"tokens": tokens, "pos": pos}, caches)
+
+    compiled = jax.jit(decode_step).lower(
+        params, _spec(one_chip, (1, 1), jnp.int32), caches,
+        _spec(one_chip, (), jnp.int32)).compile()
+    assert _device_bytes(compiled) < HBM_BYTES // 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
